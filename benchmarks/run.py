@@ -35,32 +35,12 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import json
-import os
 import sys
-import tempfile
 import time
 
 sys.path.insert(0, "src")
 
-
-def _enable_jit_cache() -> None:
-    """Dedupe XLA compilations through jax's persistent cache.  The
-    suite builds dozens of engines with identical shapes; without the
-    cache each one recompiles through LLVM, and on CPU the accumulated
-    JIT code mappings can exhaust ``vm.max_map_count`` mid-suite (LLVM
-    reports "Cannot allocate memory" with plenty of RAM free, then the
-    process segfaults).  With it, every identical HLO compiles once."""
-    import jax
-
-    cache_dir = os.environ.get(
-        "JAX_COMPILATION_CACHE_DIR",
-        os.path.join(tempfile.gettempdir(), "skymemory-jit-cache"))
-    try:
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    except AttributeError:  # older jax without the persistent cache
-        pass
+from repro.jit_cache import enable_compile_cache  # noqa: E402
 
 
 def _time_us(fn, iters=3):
@@ -420,7 +400,7 @@ def serving_throughput(quick: bool = True, smoke: bool = False,
     # run each scenario behind a cache clear: dropping the executables
     # releases their JIT code mappings (a long single process otherwise
     # accumulates enough to exhaust vm.max_map_count and abort inside
-    # LLVM), and the persistent compilation cache (_enable_jit_cache)
+    # LLVM), and the persistent compilation cache (enable_compile_cache)
     # turns any recompile into a cheap deserialize
     scenarios = [
         ("chunked_admission", _chunked_admission),
@@ -2023,7 +2003,7 @@ def main() -> None:
                          "skip the slow Table-3 end-to-end run")
     args = ap.parse_args()
 
-    _enable_jit_cache()
+    enable_compile_cache()
     print("name,us_per_call,derived")
     for bench in BENCHES:
         for name, us, derived in bench():

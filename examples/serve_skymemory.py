@@ -92,6 +92,7 @@ from repro.core import (  # noqa: E402
     plan_survivable_kills,
 )
 from repro.core.faults import FaultEvent  # noqa: E402
+from repro.jit_cache import enable_compile_cache  # noqa: E402
 from repro.models.model import Model  # noqa: E402
 from repro.serving import (  # noqa: E402
     SLO,
@@ -153,6 +154,7 @@ def main() -> None:
                          "'pro' Poisson tenant plus alternating bursty "
                          "document-reuse and diurnal tenants (--stream)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config("skymemory-tinyllama")
     if not args.full:

@@ -12,6 +12,7 @@ import sys
 sys.path.insert(0, "src")
 
 from repro.configs import get_config  # noqa: E402
+from repro.jit_cache import enable_compile_cache  # noqa: E402
 from repro.models.model import Model  # noqa: E402
 from repro.training import (  # noqa: E402
     AdamWConfig,
@@ -31,6 +32,7 @@ def main() -> None:
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--out", default="/tmp/skymemory_train_ckpt")
     args = ap.parse_args()
+    enable_compile_cache()
 
     base = get_config("skymemory-tinyllama")
     if args.tiny:

@@ -29,11 +29,6 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # jax >= 0.4.35 exports shard_map at the top level
-    _shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from repro.core.mapping import Strategy, _bfs_offsets
 
 ICI_HOP_LATENCY_S = 1e-6          # per-hop ICI latency (order of magnitude)
@@ -136,7 +131,7 @@ def migrate_shards(x: jax.Array, mesh: Mesh, *, axis: str = "data", shift: int =
     perm = [(i, (i + shift) % n) for i in range(n)]
 
     @partial(
-        _shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=P(axis),
         out_specs=P(axis),

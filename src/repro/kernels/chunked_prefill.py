@@ -1,15 +1,25 @@
-"""Pallas TPU kernel: chunked-prefill flash attention.
+"""Pallas TPU kernels: chunked-prefill flash attention, dense and paged.
 
-Computes causal (optionally sliding-window) attention where the query block
-starts ``q_offset`` tokens into the key sequence -- exactly the shape of a
+Both compute causal attention where a block of queries starts
+``q_offset`` tokens into the key sequence -- exactly the shape of a
 prefill on top of a SkyMemory-restored prefix (fresh queries over
-prefix + fresh keys).  GQA is handled by mapping each query head to its KV
-head in the BlockSpec index maps (no materialized head repeat).
+prefix + fresh keys).  Single-token decode is the one-query case of the
+paged kernel (``paged_attention.py``).
 
-Grid: (batch, q_heads, q_blocks, kv_blocks); the kv dimension is innermost,
-so the online-softmax running state (m, l, acc) lives in VMEM scratch and
-persists across kv iterations.  Block sizes default to 128 (MXU-aligned);
-the wrapper pads ragged shapes.
+Layout.  The TPU compiler accepts a block only if its last two dims are
+multiples of (8, 128) or equal to the array's own.  So:
+
+* queries are regrouped per KV head: ``[B, Hkv, nq, rep*bq, D]``, the
+  ``rep = H/Hkv`` query heads of one group times ``bq`` tokens as the rows
+  of one block (row ``r*bq + t``), whose last two dims are whole;
+* keys and values are read transposed, ``[..., Hkv, D, tokens]``: a block
+  is one KV head's ``[D, page]`` slab.  For a pool ``[N, page, Hkv, D]``
+  this is ``transpose(0, 2, 3, 1)``, which costs nothing where the device
+  keeps the page axis minor (the TPU default for this shape).
+
+Grid: (batch, kv_heads, q_blocks, kv_blocks); the kv dimension is
+innermost, so the online-softmax running state (m, l, acc) lives in VMEM
+scratch and persists across kv iterations.
 """
 from __future__ import annotations
 
@@ -23,57 +33,110 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
+MIN_ROWS = 8        # query rows per block: one sublane tile at least
 
 
-def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
-            scale: float, causal: bool, q_offset: int,
-            sliding_window: int | None, block_q: int, block_k: int,
-            kv_len: int, num_kv_blocks: int):
+def _init(m_ref, l_ref, acc_ref):
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+
+def _attend(q, kt, vt, mask, m_ref, l_ref, acc_ref, scale):
+    """One online-softmax step: query rows ``q`` [R, D] over one key block
+    given transposed, ``kt`` [D, K] and ``vt`` [Dv, K].  Masked scores
+    contribute exactly 0, even when the whole block is masked (where
+    ``m_new == NEG_INF`` would otherwise make ``exp(s - m_new) == 1``)."""
+    s = jax.lax.dot_general(
+        q, kt, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    ) * scale                                            # [R, K]
+    s = jnp.where(mask, s, NEG_INF)
+    m_prev = m_ref[...]                                  # [R, 1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+    corr = jnp.exp(m_prev - m_new)
+    l_ref[...] = corr * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
+        p.astype(vt.dtype), vt, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )                                                    # [R, Dv]
+    m_ref[...] = m_new
+
+
+def _finalize(o_ref, l_ref, acc_ref):
+    denom = jnp.maximum(l_ref[...], 1e-30)
+    o_ref[0, 0, 0] = (acc_ref[...] / denom).astype(o_ref.dtype)
+
+
+def _positions(shape, block_q: int, q_start, k_start):
+    """Absolute query / key positions of a [rows, K] score block; row
+    ``r*bq + t`` is token ``t`` of its head (``bq`` is a power of two)."""
+    t = jax.lax.broadcasted_iota(jnp.int32, shape, 0) & (block_q - 1)
+    k = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    return q_start + t, k_start + k
+
+
+def _block_q(sq: int, rep: int, block_q: int) -> int:
+    """Query tokens per block: a power of two covering short chunks, at
+    most ``block_q``, and enough that a block has ``MIN_ROWS`` rows."""
+    if block_q & (block_q - 1):
+        raise ValueError(f"block_q {block_q} is not a power of two")
+    bq = min(block_q, pl.next_power_of_2(sq))
+    while rep * bq < MIN_ROWS:
+        bq *= 2
+    return bq
+
+
+def _group_queries(q, hkv: int, bq: int):
+    """[B, Sq, H, D] -> [B, Hkv, nq, rep*bq, D] (Sq zero-padded to nq*bq)."""
+    b, sq, h, d = q.shape
+    rep = h // hkv
+    nq = -(-sq // bq)
+    q = jnp.pad(q, ((0, 0), (0, nq * bq - sq), (0, 0), (0, 0)))
+    q = q.reshape(b, nq, bq, hkv, rep, d).transpose(0, 3, 1, 4, 2, 5)
+    return q.reshape(b, hkv, nq, rep * bq, d)
+
+
+def _ungroup(out, sq: int, bq: int):
+    """Inverse of ``_group_queries``: -> [B, Sq, H, Dv]."""
+    b, hkv, nq, rows, dv = out.shape
+    rep = rows // bq
+    out = out.reshape(b, hkv, nq, rep, bq, dv).transpose(0, 2, 4, 1, 3, 5)
+    return out.reshape(b, nq * bq, hkv * rep, dv)[:, :sq]
+
+
+def _kv_major(x):
+    """[..., T, Hkv, D] -> [..., Hkv, D, T]: one KV head's [D, T] slab per
+    block, whole in its last two dims."""
+    return jnp.moveaxis(x, -3, -1)
+
+
+def _dense_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
+                  scale: float, causal: bool, q_offset: int,
+                  sliding_window: int | None, block_q: int, block_k: int,
+                  kv_len: int, num_kv_blocks: int):
+    iq = pl.program_id(2)
     ik = pl.program_id(3)
 
     @pl.when(ik == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    def _():
+        _init(m_ref, l_ref, acc_ref)
 
-    q = q_ref[0, :, 0, :].astype(jnp.float32)           # [bq, d]
-    k = k_ref[0, :, 0, :].astype(jnp.float32)           # [bk, d]
-    v = v_ref[0, :, 0, :].astype(jnp.float32)           # [bk, d]
-
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ) * scale                                            # [bq, bk]
-
-    iq = pl.program_id(2)
-    q_pos = iq * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) \
-        + q_offset
-    k_pos = ik * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    rows = q_ref.shape[-2]
+    q_pos, k_pos = _positions((rows, block_k), block_q,
+                              q_offset + iq * block_q, ik * block_k)
     mask = k_pos < kv_len
     if causal:
         mask &= k_pos <= q_pos
     if sliding_window is not None:
         mask &= k_pos > q_pos - sliding_window
-    s = jnp.where(mask, s, NEG_INF)
-
-    m_prev = m_ref[...]                                  # [bq, 1]
-    m_cur = jnp.max(s, axis=1, keepdims=True)
-    m_new = jnp.maximum(m_prev, m_cur)
-    p = jnp.exp(s - m_new)                               # [bq, bk]
-    correction = jnp.exp(m_prev - m_new)                 # [bq, 1]
-    l_new = correction * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * correction + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    m_ref[...] = m_new
-    l_ref[...] = l_new
+    _attend(q_ref[0, 0, 0], k_ref[0, 0], v_ref[0, 0], mask,
+            m_ref, l_ref, acc_ref, scale)
 
     @pl.when(ik == num_kv_blocks - 1)
-    def _finalize():
-        denom = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, :, 0, :] = (acc_ref[...] / denom).astype(o_ref.dtype)
+    def _():
+        _finalize(o_ref, l_ref, acc_ref)
 
 
 def chunked_prefill_attention(
@@ -100,51 +163,51 @@ def chunked_prefill_attention(
     scale = softmax_scale if softmax_scale is not None else dq ** -0.5
     rep = h // hkv
 
-    block_q = min(block_q, _round_up(sq))
-    block_k = min(block_k, _round_up(skv))
-    pq = (-sq) % block_q
-    pk = (-skv) % block_k
-    qp = jnp.pad(q, ((0, 0), (0, pq), (0, 0), (0, 0)))
-    kp = jnp.pad(k, ((0, 0), (0, pk), (0, 0), (0, 0)))
-    vp = jnp.pad(v, ((0, 0), (0, pk), (0, 0), (0, 0)))
-    nq = qp.shape[1] // block_q
-    nk = kp.shape[1] // block_k
+    bq = _block_q(sq, rep, block_q)
+    # a key block is a multiple of 128 lanes, or the whole (short) sequence
+    bk = min(block_k, pl.next_power_of_2(skv))
+    nk = -(-skv // bk)
+    pad = ((0, 0), (0, nk * bk - skv), (0, 0), (0, 0))
+    qg = _group_queries(q, hkv, bq)
+    kt = _kv_major(jnp.pad(k, pad))                     # [B, Hkv, Dq, Skv']
+    vt = _kv_major(jnp.pad(v, pad))                     # [B, Hkv, Dv, Skv']
+    nq, rows = qg.shape[2], qg.shape[3]
 
     kernel = functools.partial(
-        _kernel, scale=scale, causal=causal, q_offset=q_offset,
-        sliding_window=sliding_window, block_q=block_q, block_k=block_k,
+        _dense_kernel, scale=scale, causal=causal, q_offset=q_offset,
+        sliding_window=sliding_window, block_q=bq, block_k=bk,
         kv_len=skv, num_kv_blocks=nk,
     )
     out = pl.pallas_call(
         kernel,
-        grid=(b, h, nq, nk),
+        grid=(b, hkv, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, block_q, 1, dq),
-                         lambda ib, ih, iq, ik: (ib, iq, ih, 0)),
-            pl.BlockSpec((1, block_k, 1, dq),
-                         lambda ib, ih, iq, ik, rep=rep: (ib, ik, ih // rep, 0)),
-            pl.BlockSpec((1, block_k, 1, dv),
-                         lambda ib, ih, iq, ik, rep=rep: (ib, ik, ih // rep, 0)),
+            pl.BlockSpec((1, 1, 1, rows, dq),
+                         lambda ib, ig, iq, ik: (ib, ig, iq, 0, 0)),
+            pl.BlockSpec((1, 1, dq, bk),
+                         lambda ib, ig, iq, ik: (ib, ig, 0, ik)),
+            pl.BlockSpec((1, 1, dv, bk),
+                         lambda ib, ig, iq, ik: (ib, ig, 0, ik)),
         ],
-        out_specs=pl.BlockSpec((1, block_q, 1, dv),
-                               lambda ib, ih, iq, ik: (ib, iq, ih, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, qp.shape[1], h, dv), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, 1, rows, dv),
+                               lambda ib, ig, iq, ik: (ib, ig, iq, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, hkv, nq, rows, dv), q.dtype),
         scratch_shapes=[
-            pltpu.VMEM((block_q, 1), jnp.float32),   # running max
-            pltpu.VMEM((block_q, 1), jnp.float32),   # running denom
-            pltpu.VMEM((block_q, dv), jnp.float32),  # output accumulator
+            pltpu.VMEM((rows, 1), jnp.float32),    # running max
+            pltpu.VMEM((rows, 1), jnp.float32),    # running denom
+            pltpu.VMEM((rows, dv), jnp.float32),   # output accumulator
         ],
+        name="chunked_prefill_attention",
         interpret=interpret,
-    )(qp, kp, vp)
-    return out[:, :sq]
+    )(qg, kt, vt)
+    return _ungroup(out, sq, bq)
 
 
-def _kernel_paged(len_ref, off_ref, bt_ref, q_ref, k_ref, v_ref, o_ref,
+def _paged_kernel(len_ref, off_ref, bt_ref, q_ref, k_ref, v_ref, o_ref,
                   m_ref, l_ref, acc_ref, *, scale: float, page: int,
                   block_q: int, num_pages: int):
-    """Paged variant: q is a prefill *chunk* whose keys live in a shared
-    page pool; the page id for (sequence, page-slot) was resolved in the
-    index map from the scalar-prefetched block table, and the causal
+    """Paged variant: the page id for (sequence, page-slot) was resolved in
+    the index map from the scalar-prefetched block table, and the causal
     offset / valid length arrive per sequence through SMEM (they are
     traced values in the serving engine's fused step, not compile-time
     constants like the dense kernel's ``q_offset``)."""
@@ -153,43 +216,19 @@ def _kernel_paged(len_ref, off_ref, bt_ref, q_ref, k_ref, v_ref, o_ref,
     ip = pl.program_id(3)
 
     @pl.when(ip == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    def _():
+        _init(m_ref, l_ref, acc_ref)
 
-    q = q_ref[0, :, 0, :].astype(jnp.float32)            # [bq, d]
-    k = k_ref[0, :, 0, :].astype(jnp.float32)            # [page, d]
-    v = v_ref[0, :, 0, :].astype(jnp.float32)            # [page, d]
-
-    s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    ) * scale                                            # [bq, page]
-
-    q_pos = iq * block_q + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) \
-        + off_ref[ib]
-    k_pos = ip * page + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    rows = q_ref.shape[-2]
+    q_pos, k_pos = _positions((rows, page), block_q,
+                              off_ref[ib] + iq * block_q, ip * page)
     mask = (k_pos <= q_pos) & (k_pos < len_ref[ib])
-    s = jnp.where(mask, s, NEG_INF)
-
-    m_prev = m_ref[...]                                  # [bq, 1]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-    # masked scores contribute exactly 0 even when the whole page is masked
-    # (m_new == NEG_INF would otherwise make exp(s - m_new) == 1)
-    p = jnp.where(s <= NEG_INF * 0.5, 0.0, jnp.exp(s - m_new))
-    corr = jnp.exp(m_prev - m_new)
-    l_ref[...] = corr * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    m_ref[...] = m_new
+    _attend(q_ref[0, 0, 0], k_ref[0, 0], v_ref[0, 0], mask,
+            m_ref, l_ref, acc_ref, scale)
 
     @pl.when(ip == num_pages - 1)
-    def _finalize():
-        denom = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, :, 0, :] = (acc_ref[...] / denom).astype(o_ref.dtype)
+    def _():
+        _finalize(o_ref, l_ref, acc_ref)
 
 
 def chunked_prefill_paged(
@@ -216,54 +255,43 @@ def chunked_prefill_paged(
     scale = softmax_scale if softmax_scale is not None else dq ** -0.5
     rep = h // hkv
 
-    block_q = min(block_q, _round_up(sq))
-    pq = (-sq) % block_q
-    qp = jnp.pad(q, ((0, 0), (0, pq), (0, 0), (0, 0)))
-    nq = qp.shape[1] // block_q
+    bq = _block_q(sq, rep, block_q)
+    qg = _group_queries(q, hkv, bq)
+    nq, rows = qg.shape[2], qg.shape[3]
 
     kernel = functools.partial(
-        _kernel_paged, scale=scale, page=page, block_q=block_q,
-        num_pages=np_,
+        _paged_kernel, scale=scale, page=page, block_q=bq, num_pages=np_,
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(b, h, nq, np_),
+        grid=(b, hkv, nq, np_),
         in_specs=[
-            pl.BlockSpec((1, block_q, 1, dq),
-                         lambda ib, ih, iq, ip, lens, offs, bt:
-                             (ib, iq, ih, 0)),
-            pl.BlockSpec((1, page, 1, dq),
-                         lambda ib, ih, iq, ip, lens, offs, bt, rep=rep:
-                             (bt[ib, ip], 0, ih // rep, 0)),
-            pl.BlockSpec((1, page, 1, dv),
-                         lambda ib, ih, iq, ip, lens, offs, bt, rep=rep:
-                             (bt[ib, ip], 0, ih // rep, 0)),
+            pl.BlockSpec((1, 1, 1, rows, dq),
+                         lambda ib, ig, iq, ip, lens, offs, bt:
+                             (ib, ig, iq, 0, 0)),
+            pl.BlockSpec((1, 1, dq, page),
+                         lambda ib, ig, iq, ip, lens, offs, bt:
+                             (bt[ib, ip], ig, 0, 0)),
+            pl.BlockSpec((1, 1, dv, page),
+                         lambda ib, ig, iq, ip, lens, offs, bt:
+                             (bt[ib, ip], ig, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_q, 1, dv),
-                               lambda ib, ih, iq, ip, lens, offs, bt:
-                                   (ib, iq, ih, 0)),
+        out_specs=pl.BlockSpec((1, 1, 1, rows, dv),
+                               lambda ib, ig, iq, ip, lens, offs, bt:
+                                   (ib, ig, iq, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((block_q, 1), jnp.float32),   # running max
-            pltpu.VMEM((block_q, 1), jnp.float32),   # running denom
-            pltpu.VMEM((block_q, dv), jnp.float32),  # output accumulator
+            pltpu.VMEM((rows, 1), jnp.float32),    # running max
+            pltpu.VMEM((rows, 1), jnp.float32),    # running denom
+            pltpu.VMEM((rows, dv), jnp.float32),   # output accumulator
         ],
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, qp.shape[1], h, dv), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, hkv, nq, rows, dv), q.dtype),
+        name="chunked_prefill_paged",
         interpret=interpret,
     )(lengths.astype(jnp.int32), q_offsets.astype(jnp.int32),
-      block_tables.astype(jnp.int32), qp, k_pool, v_pool)
-    return out[:, :sq]
-
-
-def _round_up(n: int, mult: int = 128) -> int:
-    return max(mult, -(-n // mult) * mult) if n >= mult else _pow2(n)
-
-
-def _pow2(n: int) -> int:
-    p = 1
-    while p < n:
-        p *= 2
-    return p
+      block_tables.astype(jnp.int32), qg, _kv_major(k_pool),
+      _kv_major(v_pool))
+    return _ungroup(out, sq, bq)

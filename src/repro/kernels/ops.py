@@ -1,12 +1,11 @@
 """jit'd kernel entry points with implementation dispatch.
 
 ``impl``:
-  * ``"auto"``    -- Pallas on TPU backends, pure-jnp reference elsewhere
-                     (this CPU container always takes the jnp path unless
-                     interpret mode is forced);
+  * ``"auto"``    -- compiled Pallas kernels on a TPU backend, the jnp
+                     oracle on any other (the CPU test runs);
   * ``"jnp"``     -- the ref.py oracle;
   * ``"pallas"``  -- the Pallas TPU kernel (compiled on TPU, interpret=True
-                     on CPU so correctness is testable in this container).
+                     elsewhere, so kernel logic is testable on the CPU).
 
 Override globally with the ``REPRO_KERNEL_IMPL`` environment variable.
 """

@@ -4,159 +4,17 @@ The cache is the block-paged tensor SkyMemory stripes: pages of
 ``page_size`` tokens (the paper's 128-token blocks) per sequence.  One
 query per sequence attends over all valid pages with online softmax.
 
-Grid: (batch, q_heads, pages); pages innermost so the running (m, l, acc)
-scratch carries across page iterations.  The per-sequence valid length
-arrives as a [B, 1] int32 operand read from its own block.  GQA maps query
-head -> kv head in the index maps.
+Decode is the one-token chunk of ``chunked_prefill_paged``: the query
+sits at position ``length - 1`` and sees every key before it, so the same
+kernel (its block layout, scalar-prefetched block table and masking)
+serves both.  Each grid step takes all ``H/Hkv`` query heads of one KV
+group against one page.
 """
 from __future__ import annotations
 
-import functools
-
-import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-NEG_INF = -1e30
-
-
-def _kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
-            scale: float, page: int, num_pages: int):
-    ip = pl.program_id(2)
-
-    @pl.when(ip == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    q = q_ref[0, 0, :].astype(jnp.float32)               # [d]
-    k = k_ref[0, 0, :, 0, :].astype(jnp.float32)         # [page, d]
-    v = v_ref[0, 0, :, 0, :].astype(jnp.float32)         # [page, d]
-    length = len_ref[0, 0]
-
-    s = jax.lax.dot_general(
-        k, q[:, None], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )[:, 0] * scale                                      # [page]
-    pos = ip * page + jax.lax.iota(jnp.int32, page)
-    s = jnp.where(pos < length, s, NEG_INF)
-    s = s[None, :]                                       # [1, page]
-
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-    # masked scores contribute exactly 0 even when the whole page is masked
-    # (m_new == NEG_INF would otherwise make exp(s - m_new) == 1)
-    p = jnp.where(s <= NEG_INF * 0.5, 0.0, jnp.exp(s - m_new))
-    corr = jnp.exp(m_prev - m_new)
-    l_ref[...] = corr * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    m_ref[...] = m_new
-
-    @pl.when(ip == num_pages - 1)
-    def _finalize():
-        denom = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0, :] = (acc_ref[...] / denom)[0].astype(o_ref.dtype)
-
-
-def _kernel_bt(len_ref, bt_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref,
-               acc_ref, *, scale: float, page: int, num_pages: int):
-    """Block-table variant: k/v arrive from a shared page pool; the page id
-    for (sequence, page-slot) was resolved in the index map from the
-    scalar-prefetched block table.  Only the length read differs here."""
-    ib = pl.program_id(0)
-    ip = pl.program_id(2)
-
-    @pl.when(ip == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    q = q_ref[0, 0, :].astype(jnp.float32)               # [d]
-    k = k_ref[0, :, 0, :].astype(jnp.float32)            # [page, d]
-    v = v_ref[0, :, 0, :].astype(jnp.float32)            # [page, d]
-    length = len_ref[ib]
-
-    s = jax.lax.dot_general(
-        k, q[:, None], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )[:, 0] * scale                                      # [page]
-    pos = ip * page + jax.lax.iota(jnp.int32, page)
-    s = jnp.where(pos < length, s, NEG_INF)
-    s = s[None, :]                                       # [1, page]
-
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-    # masked scores contribute exactly 0 even when the whole page is masked
-    # (m_new == NEG_INF would otherwise make exp(s - m_new) == 1)
-    p = jnp.where(s <= NEG_INF * 0.5, 0.0, jnp.exp(s - m_new))
-    corr = jnp.exp(m_prev - m_new)
-    l_ref[...] = corr * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
-    m_ref[...] = m_new
-
-    @pl.when(ip == num_pages - 1)
-    def _finalize():
-        denom = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0, :] = (acc_ref[...] / denom)[0].astype(o_ref.dtype)
-
-
-def _paged_attention_bt(q, k_pool, v_pool, lengths, block_tables, *,
-                        softmax_scale, interpret):
-    """Pool layout: k/v [N, page, Hkv, D]; block_tables [B, P] page ids.
-
-    The block table and lengths ride scalar prefetch (SMEM), so the k/v
-    index maps can dereference ``bt[ib, ip]`` -- pages stream straight from
-    the pool with no per-sequence gather/copy on the host or in HBM.
-    """
-    b, h, d = q.shape
-    _, page, hkv, _ = k_pool.shape
-    np_ = block_tables.shape[1]
-    dv = v_pool.shape[-1]
-    scale = softmax_scale if softmax_scale is not None else d ** -0.5
-    rep = h // hkv
-
-    kernel = functools.partial(_kernel_bt, scale=scale, page=page,
-                               num_pages=np_)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, h, np_),
-        in_specs=[
-            pl.BlockSpec((1, 1, d), lambda ib, ih, ip, lens, bt: (ib, ih, 0)),
-            pl.BlockSpec(
-                (1, page, 1, d),
-                lambda ib, ih, ip, lens, bt, rep=rep:
-                    (bt[ib, ip], 0, ih // rep, 0),
-            ),
-            pl.BlockSpec(
-                (1, page, 1, dv),
-                lambda ib, ih, ip, lens, bt, rep=rep:
-                    (bt[ib, ip], 0, ih // rep, 0),
-            ),
-        ],
-        out_specs=pl.BlockSpec((1, 1, dv),
-                               lambda ib, ih, ip, lens, bt: (ib, ih, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, dv), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, dv), q.dtype),
-        interpret=interpret,
-    )(lengths.astype(jnp.int32), block_tables.astype(jnp.int32),
-      q, k_pool, v_pool)
+from repro.kernels.chunked_prefill import chunked_prefill_paged
 
 
 def paged_attention(
@@ -169,38 +27,17 @@ def paged_attention(
 
     With ``block_tables`` [B,P], k/v are instead a shared page pool
     [N,page,Hkv,D] and each sequence's pages are resolved through its
-    block-table row (scalar prefetch)."""
-    if block_tables is not None:
-        return _paged_attention_bt(
-            q, k_pages, v_pages, lengths, block_tables,
-            softmax_scale=softmax_scale, interpret=interpret,
-        )
-    b, h, d = q.shape
-    _, np_, page, hkv, _ = k_pages.shape
-    dv = v_pages.shape[-1]
-    scale = softmax_scale if softmax_scale is not None else d ** -0.5
-    rep = h // hkv
-    lengths2 = lengths.reshape(b, 1).astype(jnp.int32)
-
-    kernel = functools.partial(_kernel, scale=scale, page=page,
-                               num_pages=np_)
-    return pl.pallas_call(
-        kernel,
-        grid=(b, h, np_),
-        in_specs=[
-            pl.BlockSpec((1, 1), lambda ib, ih, ip: (ib, 0)),
-            pl.BlockSpec((1, 1, d), lambda ib, ih, ip: (ib, ih, 0)),
-            pl.BlockSpec((1, 1, page, 1, d),
-                         lambda ib, ih, ip, rep=rep: (ib, ip, 0, ih // rep, 0)),
-            pl.BlockSpec((1, 1, page, 1, dv),
-                         lambda ib, ih, ip, rep=rep: (ib, ip, 0, ih // rep, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, dv), lambda ib, ih, ip: (ib, ih, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, h, dv), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, 1), jnp.float32),
-            pltpu.VMEM((1, dv), jnp.float32),
-        ],
-        interpret=interpret,
-    )(lengths2, q, k_pages, v_pages)
+    block-table row (scalar prefetch).  Without, the per-sequence pages
+    are viewed as a pool of ``B*P`` pages with an arithmetic table.  A row
+    with ``lengths == 0`` returns zeros."""
+    if block_tables is None:
+        b, p = k_pages.shape[:2]
+        k_pages = k_pages.reshape((b * p,) + k_pages.shape[2:])
+        v_pages = v_pages.reshape((b * p,) + v_pages.shape[2:])
+        block_tables = jnp.arange(b * p, dtype=jnp.int32).reshape(b, p)
+    lengths = lengths.astype(jnp.int32)
+    out = chunked_prefill_paged(
+        q[:, None], k_pages, v_pages, lengths, block_tables, lengths - 1,
+        softmax_scale=softmax_scale, interpret=interpret,
+    )
+    return out[:, 0]

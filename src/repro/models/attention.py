@@ -95,24 +95,19 @@ def attention_prefill_paged(
     """A batch of prefill chunks against the shared page pool; returns
     ``(out, k_pool', v_pool')``.
 
-    Each row's chunk K/V are scattered into its slot's pages *first*
-    (per-token, so a chunk start need not be page-aligned -- the
-    whole-prompt-cached replay starts one token before a block
-    boundary), then the chunk's queries attend over everything valid so
-    far: SkyMemory-restored pages, earlier chunks, and this chunk, all
-    read in place through the block tables.  Positions past ``n_valid``
-    (the padded tail of a ragged final chunk, or an all-padding batch
-    row) are dropped from the write (their page id is pushed out of
-    range with scatter mode ``drop``) and their outputs are garbage the
-    scheduler never reads.  ``q_offsets`` / ``n_valid`` are traced
-    values: one compilation per chunk-buffer shape serves every chunk of
-    every admission.
+    Each row's chunk K/V are written into its slot's pages *first*
+    (a chunk start need not be page-aligned -- the whole-prompt-cached
+    replay starts one token before a block boundary), then the chunk's
+    queries attend over everything valid so far: SkyMemory-restored
+    pages, earlier chunks, and this chunk, all read in place through the
+    block tables.  Positions past ``n_valid`` (the padded tail of a
+    ragged final chunk, or an all-padding batch row) are left out of the
+    write and their outputs are garbage the scheduler never reads.
+    ``q_offsets`` / ``n_valid`` are traced values: one compilation per
+    chunk-buffer shape serves every chunk of every admission.
     """
     r, c = x.shape[0], x.shape[1]
     h, hd = cfg.num_heads, cfg.head_dim
-    page = k_pool.shape[1]
-    n_pages = k_pool.shape[0]
-    num_tables = block_tables.shape[1]
     q_offsets = jnp.asarray(q_offsets, jnp.int32)
     n_valid = jnp.asarray(n_valid, jnp.int32)
 
@@ -124,18 +119,11 @@ def attention_prefill_paged(
     k_new = maybe_shard(k_new, "decode_qkv")
     v_new = maybe_shard(v_new, "decode_qkv")
 
-    row_ok = jnp.arange(c)[None, :] < n_valid[:, None]             # [R, C]
-    table_idx = jnp.clip(positions // page, 0, num_tables - 1)
-    page_ids = jnp.take_along_axis(block_tables, table_idx, axis=1)
-    page_ids = jnp.where(row_ok, page_ids, n_pages)        # OOB -> dropped
-    slots = positions % page
     int8_kvc = k_pool.dtype == jnp.int8
     if int8_kvc:
         k_new, v_new = _quant(k_new), _quant(v_new)
-    k_pool = k_pool.at[page_ids, slots].set(
-        k_new.astype(k_pool.dtype), mode="drop")
-    v_pool = v_pool.at[page_ids, slots].set(
-        v_new.astype(v_pool.dtype), mode="drop")
+    k_pool = _write_chunks(k_pool, k_new, block_tables, q_offsets, n_valid)
+    v_pool = _write_chunks(v_pool, v_new, block_tables, q_offsets, n_valid)
     if int8_kvc:
         k_read = _dequant(k_pool, x.dtype)
         v_read = _dequant(v_pool, x.dtype)
@@ -222,8 +210,8 @@ def attention_decode_paged(
 
     Per-sequence positions are heterogeneous (slots admit mid-decode), so
     RoPE, the page write, and the attention mask are all driven by
-    ``lengths``.  The new K/V is scattered into the page holding position
-    ``lengths[b]`` -- pages are exclusive to a slot, so the scatter rows
+    ``lengths``.  The new K/V is written into the page holding position
+    ``lengths[b]`` -- pages are exclusive to a slot, so the rows' writes
     never collide (idle slots write into their own region / the scratch
     page, which the next admission overwrites).
 
@@ -256,8 +244,8 @@ def attention_decode_paged(
     int8_kvc = k_pool.dtype == jnp.int8
     if int8_kvc:
         k_new, v_new = _quant(k_new), _quant(v_new)
-    k_pool = k_pool.at[page_ids, slots].set(k_new[:, 0].astype(k_pool.dtype))
-    v_pool = v_pool.at[page_ids, slots].set(v_new[:, 0].astype(v_pool.dtype))
+    k_pool = _write_tokens(k_pool, k_new[:, 0], page_ids, slots)
+    v_pool = _write_tokens(v_pool, v_new[:, 0], page_ids, slots)
     if int8_kvc:
         k_read = _dequant(k_pool, x.dtype)
         v_read = _dequant(v_pool, x.dtype)
@@ -275,6 +263,45 @@ def attention_decode_paged(
             q[:, 0], k_read, v_read, pos + 1, block_tables=block_tables
         )
     return out.reshape(b, 1, h * hd) @ params["wo"], k_pool, v_pool
+
+
+# Pool writes are row-by-row dynamic_update_slices, not scatters: they
+# update the pool in place in whatever layout the device keeps it, where a
+# scatter makes XLA re-lay the pool's layer out around it.
+
+def _write_tokens(pool, new, page_ids, slots):
+    """One token per row: ``new[b]`` [Hkv, hd] at ``(page_ids[b],
+    slots[b])`` of ``pool`` [N_pages, page, Hkv, hd]."""
+    for b in range(new.shape[0]):
+        pool = jax.lax.dynamic_update_slice(
+            pool, new[b][None, None].astype(pool.dtype),
+            (page_ids[b], slots[b], 0, 0))
+    return pool
+
+
+def _write_chunks(pool, new, block_tables, q_offsets, n_valid):
+    """Row ``r``'s first ``n_valid[r]`` tokens of ``new`` [R, C, Hkv, hd]
+    at absolute positions ``q_offsets[r] + i``, through its block-table
+    row.  A chunk need not start on a page boundary, so each page it can
+    touch is read, blended with the chunk's tokens and written back whole;
+    positions outside ``[q_offsets, q_offsets + n_valid)`` keep what the
+    page held (an all-padding row rewrites its pages unchanged)."""
+    r, c = new.shape[:2]
+    page = pool.shape[1]
+    num_tables = block_tables.shape[1]
+    span = (c + page - 2) // page + 1       # pages C tokens can straddle
+    for i in range(r):
+        for j in range(span):
+            p_idx = q_offsets[i] // page + j
+            t = p_idx * page + jnp.arange(page, dtype=jnp.int32) - q_offsets[i]
+            valid = (t >= 0) & (t < n_valid[i]) & (p_idx < num_tables)
+            pid = block_tables[i, jnp.minimum(p_idx, num_tables - 1)]
+            old = jax.lax.dynamic_slice_in_dim(pool, pid, 1, axis=0)[0]
+            vals = jnp.take(new[i], jnp.clip(t, 0, c - 1), axis=0)
+            blended = jnp.where(valid[:, None, None],
+                                vals.astype(pool.dtype), old)
+            pool = jax.lax.dynamic_update_index_in_dim(pool, blended, pid, 0)
+    return pool
 
 
 def _paged(q, k_cache, v_cache, lengths):

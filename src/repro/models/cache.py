@@ -15,9 +15,12 @@ Two layouts:
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental import pallas as pl
 
 from repro.models.config import ModelConfig
 
@@ -303,20 +306,26 @@ class PagedKVCache:
                 f"slot {slot}: span end {end} beyond allocated pages")
         self.cursors[slot] = max(self.cursors[slot], end)
 
-    # -- page writes (host side, outside the jitted step) ---------------
+    # -- page writes (jitted, pools donated) ----------------------------
     def write_pages(self, slot: int, first_page: int, k_blocks, v_blocks):
         """Drop whole pages into the pool: ``k_blocks``/``v_blocks`` are
         ``[layers, n_pages, page_size, kv_heads, head_dim]`` -- e.g. blocks
-        fetched from the constellation, already page-shaped.  No dense
-        restacking: one scatter per pool array."""
+        fetched from the constellation, already page-shaped.  One jitted
+        scatter that donates both pools and writes them in place, so a
+        restore never holds a second pool.  Spans are padded to a power of
+        two pages (padding rows target a page past the pool and are
+        dropped), so a handful of compilations serve every span."""
         n = k_blocks.shape[1]
-        ids = jnp.asarray(
-            self._slot_pages[slot][first_page:first_page + n], jnp.int32)
-        if ids.shape[0] != n:
+        ids = self._slot_pages[slot][first_page:first_page + n]
+        if len(ids) != n:
             raise RuntimeError("write_pages beyond allocated pages")
         k_blocks, v_blocks = self._cast(k_blocks), self._cast(v_blocks)
-        self.k_pool = self.k_pool.at[:, ids].set(k_blocks)
-        self.v_pool = self.v_pool.at[:, ids].set(v_blocks)
+        width = pl.next_power_of_2(n)
+        pad = ((0, 0), (0, width - n), (0, 0), (0, 0), (0, 0))
+        self.k_pool, self.v_pool = _put_pages(
+            self.k_pool, self.v_pool,
+            jnp.asarray(ids + [self.num_pages] * (width - n), jnp.int32),
+            jnp.pad(k_blocks, pad), jnp.pad(v_blocks, pad))
         self.cursors[slot] = max(self.cursors[slot],
                                  (first_page + n) * self.page_size)
 
@@ -343,6 +352,14 @@ class PagedKVCache:
         if self.dtype == jnp.int8 and x.dtype != jnp.int8:
             return quant_kvc(x)
         return x.astype(self.dtype)
+
+
+@functools.partial(jax.jit, donate_argnums=(0, 1))
+def _put_pages(k_pool, v_pool, ids, k_blocks, v_blocks):
+    """Pages ``[:, i]`` of the blocks into pool pages ``ids[i]``; ids past
+    the pool are dropped."""
+    return (k_pool.at[:, ids].set(k_blocks, mode="drop"),
+            v_pool.at[:, ids].set(v_blocks, mode="drop"))
 
 
 def cache_bytes(cfg: ModelConfig, batch: int, seq_len: int) -> int:

@@ -149,17 +149,15 @@ class PagedExecutor:
         self.pool.k_pool, self.pool.v_pool = kp, vp
         return lg
 
-    def prefill_chunk_eager(self, tokens_row, bt_row, start: int, v: int):
-        """A single unjitted chunk over the pool (stop-the-world suffix
-        prefill and restore-tail replay; shapes vary per call, so jitting
-        would only grow the compile cache)."""
-        lg, kp, vp = self.model.prefill_chunk_paged(
-            self.params, self.pool.k_pool, self.pool.v_pool,
-            jnp.asarray(tokens_row), jnp.asarray(bt_row),
-            jnp.asarray([start], jnp.int32), jnp.asarray([v], jnp.int32),
-        )
-        self.pool.k_pool, self.pool.v_pool = kp, vp
-        return lg[0]
+    def prefill_chunk_one(self, tokens_row, bt_row, start: int, v: int):
+        """A single chunk over the pool (stop-the-world suffix prefill and
+        restore-tail replay): the chunk-wave program on one row, its
+        buffer padded to a length bucket so a few compilations serve every
+        span."""
+        buf = np.zeros((1, self.bucket(v)), np.int32)
+        buf[0, :v] = np.asarray(tokens_row).reshape(-1)[:v]
+        return self.chunk_wave(buf, bt_row, np.asarray([start], np.int32),
+                               np.asarray([v], np.int32))[0]
 
     def prefill_dense(self, toks):
         """Batched bucketed dense prefill (stop-the-world misses)."""

@@ -810,7 +810,7 @@ class Scheduler:
         self.chunk_log.append((slot, start, v))
         toks = np.asarray(s.tokens[start:], np.int32)[None]
         bt_row = np.asarray(self.kv.pool.table_row(slot)[None], np.int32)
-        return self.ex.prefill_chunk_eager(toks, bt_row, start, v)
+        return self.ex.prefill_chunk_one(toks, bt_row, start, v)
 
     def _replay_tail(self, s: Seq, slot: int) -> None:
         """Restore replay, stop-the-world flavor: the tokens past the
@@ -824,7 +824,7 @@ class Scheduler:
         self.chunk_log.append((slot, start, v))
         buf = np.asarray(toks[start:], np.int32)[None]
         bt_row = np.asarray(self.kv.pool.table_row(slot)[None], np.int32)
-        self.ex.prefill_chunk_eager(buf, bt_row, start, v)
+        self.ex.prefill_chunk_one(buf, bt_row, start, v)
         self.stats.prefill_chunks += 1
         s.cursor = len(toks)
 

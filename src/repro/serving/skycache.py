@@ -67,13 +67,7 @@ class SkyKVCAdapter:
         else:
             values = 2 * cfg.num_kv_heads * cfg.head_dim
         values *= cfg.num_layers
-        itemsize = np.dtype(np.float32).itemsize
-        try:
-            itemsize = np.dtype(cfg.dtype).itemsize
-        except TypeError:
-            import ml_dtypes
-
-            itemsize = np.dtype(getattr(ml_dtypes, cfg.dtype)).itemsize
+        itemsize = jnp.dtype(cfg.dtype).itemsize
         return values * self.codec.bytes_per_value(itemsize)
 
     # -- state <-> payload ------------------------------------------------

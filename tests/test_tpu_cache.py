@@ -74,18 +74,9 @@ def test_migrate_shards_single_device_identity():
     np.testing.assert_allclose(np.asarray(y), np.asarray(x))
 
 
-def _abstract_mesh(n: int, name: str):
-    """AbstractMesh across jax versions: (sizes, names) on new jax,
-    a ((name, size), ...) shape tuple on 0.4.x."""
-    try:
-        return jax.sharding.AbstractMesh((n,), (name,))
-    except TypeError:
-        return jax.sharding.AbstractMesh(((name, n),))
-
-
 def test_migrate_shards_lowering_multidevice():
     """lower() the migration collective against an abstract 4-device mesh."""
-    mesh = _abstract_mesh(4, "data")
+    mesh = jax.sharding.AbstractMesh((4,), ("data",))
     x = jax.ShapeDtypeStruct((8, 2), jnp.float32)
 
     def fn(v):
