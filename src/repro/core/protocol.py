@@ -45,6 +45,7 @@ from repro.core.hashing import chain_hashes, split_token_blocks
 from repro.core.mapping import Strategy, place_servers
 from repro.core.radix import BlockMeta, RadixBlockIndex
 from repro.core.store import SatelliteStore
+from repro.trace import span
 
 
 # ---------------------------------------------------------------------------
@@ -1805,7 +1806,9 @@ class KVCManager:
             if n_cached:
                 # lazily-evicted tails (or broken delta chains) shrink
                 # the resumable prefix; a None past means recompute all
-                past, n_cached = self._fetch_cumulative(hashes, n_cached)
+                with span("write_back.resume"):
+                    past, n_cached = self._fetch_cumulative(hashes,
+                                                            n_cached)
         payloads: list[bytes] = []
         for i in range(n_cached, len(hashes)):
             block_tokens = [t for b in blocks[: i + 1] for t in b]
@@ -1819,7 +1822,7 @@ class KVCManager:
                 past = payload
         if not payloads:
             return 0
-        with self.lock:
+        with self.lock, span("fabric.set"):
             metas: list[BlockMeta | None] = [None] * len(hashes)
             stored_upto = len(hashes)
             for i, payload in zip(range(n_cached, len(hashes)), payloads):
@@ -1894,7 +1897,7 @@ class KVCManager:
         hashes = chain_hashes(tokens, self.block_size)
         if not hashes:
             return None, 0
-        with self.lock:
+        with self.lock, span("fabric.get"):
             if self.use_radix:
                 n, _meta = self.index.longest_cached_prefix(hashes)
             else:

@@ -235,6 +235,7 @@ def chunked_prefill_paged(
     q, k_pool, v_pool, lengths, block_tables, q_offsets, *,
     softmax_scale: float | None = None,
     block_q: int = DEFAULT_BLOCK_Q,
+    name: str = "chunked_prefill_paged",
     interpret: bool = False,
 ):
     """Chunked prefill reading keys straight from a shared page pool.
@@ -247,7 +248,8 @@ def chunked_prefill_paged(
     serves every chunk of a prefill as it advances -- and the prefix pages
     (SkyMemory-restored blocks, earlier chunks) are read in place, never
     gathered into a contiguous per-sequence tensor.  Fully masked query
-    rows (padded chunk tail, ``lengths == 0``) return zeros.
+    rows (padded chunk tail, ``lengths == 0``) return zeros.  ``name``
+    names the kernel's call in a device trace.
     """
     b, sq, h, dq = q.shape
     _, page, hkv, dv = v_pool.shape
@@ -289,7 +291,7 @@ def chunked_prefill_paged(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, nq, rows, dv), q.dtype),
-        name="chunked_prefill_paged",
+        name=name,
         interpret=interpret,
     )(lengths.astype(jnp.int32), q_offsets.astype(jnp.int32),
       block_tables.astype(jnp.int32), qg, _kv_major(k_pool),

@@ -8,7 +8,9 @@ Decode is the one-token chunk of ``chunked_prefill_paged``: the query
 sits at position ``length - 1`` and sees every key before it, so the same
 kernel (its block layout, scalar-prefetched block table and masking)
 serves both.  Each grid step takes all ``H/Hkv`` query heads of one KV
-group against one page.
+group against one page.  Its calls are named
+``chunked_prefill_paged_decode``, so a device trace tells decode from
+chunk time and still matches both under ``chunked_prefill_paged``.
 """
 from __future__ import annotations
 
@@ -38,6 +40,7 @@ def paged_attention(
     lengths = lengths.astype(jnp.int32)
     out = chunked_prefill_paged(
         q[:, None], k_pages, v_pages, lengths, block_tables, lengths - 1,
-        softmax_scale=softmax_scale, interpret=interpret,
+        softmax_scale=softmax_scale, name="chunked_prefill_paged_decode",
+        interpret=interpret,
     )
     return out[:, 0]

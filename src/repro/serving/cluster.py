@@ -80,6 +80,7 @@ from repro.serving.slo import (
 from repro.serving.stats import EngineStats
 from repro.serving.tokenizer import ByteTokenizer, truncate_prompt
 from repro.serving.traffic import Arrival
+from repro.trace import span
 
 
 @dataclass
@@ -293,8 +294,9 @@ class EngineCluster:
         them to the caller (the end-of-run baseline)."""
         toks = truncate_prompt(self.tokenizer.encode(request.prompt),
                                self.max_seq_len)
-        d = self.router.route(
-            toks, est_new_tokens=request.sampling.max_new_tokens)
+        with span("router.route", rid=request.request_id):
+            d = self.router.route(
+                toks, est_new_tokens=request.sampling.max_new_tokens)
         self.decisions.append(d)
         fut = self.engines[d.replica].submit(request)
         if release:

@@ -245,7 +245,6 @@ class DenseRuntime:
         return Seq(request=req, tokens=tokens, enqueue_t=time.perf_counter())
 
     def _prefill_one(self, req) -> Seq:
-        t0 = time.perf_counter()
         s = self._make_seq(req)
         tokens = s.tokens
         cached = 0
@@ -268,7 +267,6 @@ class DenseRuntime:
             lg, _, state = self.model.forward(
                 self.params, toks, collect_state=True
             )
-        self.stats.prefill_time_s += time.perf_counter() - t0
         self.stats.cached_tokens += cached
         self.stats.prefilled_tokens += len(tokens) - cached
         if self.write_back and self.manager is not None:

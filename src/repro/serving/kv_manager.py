@@ -53,6 +53,7 @@ from repro.core.protocol import KVCManager
 from repro.models.cache import PagedKVCache
 from repro.serving.skycache import SkyKVCAdapter
 from repro.serving.stats import EngineStats
+from repro.trace import span
 
 
 @dataclass
@@ -231,21 +232,19 @@ class TieredKVManager:
         if self.manager is None:
             return 0
         self.drain_write_back()
-        if self._transport is not None:
-            self._transport.last_ready_at = None
-        with self._observe_l2():
-            payload, cached = self.manager.get_cache_tokens(tokens)
+        payload, cached = self._get(tokens)
         if payload is None or not cached:
             return 0
         # a restore is already a stall point: experience the Get's flight
         # time here rather than deferring (nothing else can run for this
         # slot until its pages are back)
         if self._transport is not None:
-            self.wait_fetch(self._transport.last_ready_at)
+            self.wait_fetch(self._transport.last_ready_at, rid=key)
         cached = min(cached, len(tokens))
         k_blocks, v_blocks = self.adapter.payload_to_pages(
             payload, cached, self.pool.page_size)
-        self.pool.write_pages(slot, 0, k_blocks, v_blocks)
+        with span("kv.page_import", rid=key):
+            self.pool.write_pages(slot, 0, k_blocks, v_blocks)
         return cached
 
     def _spill_to_l2(self, key, entry: HostEntry) -> None:
@@ -330,35 +329,44 @@ class TieredKVManager:
         request."""
         if self.manager is None:
             return None, 0, None
-        self.drain_write_back()
-        if self._transport is not None:
-            self._transport.last_ready_at = None
-        with self._observe_l2():
-            payload, cached = self.manager.get_cache_tokens(tokens)
+        self.stats.write_back_wait_s += self.drain_write_back()
+        payload, cached = self._get(tokens)
         ready_at = None
         if (payload is not None and self._transport is not None
                 and self.clock is not None):
             ready_at = self._transport.last_ready_at
         return payload, cached, ready_at
 
+    def _get(self, tokens: list[int]) -> tuple[bytes | None, int]:
+        """One Get KVC on the constellation, counted and timed."""
+        if self._transport is not None:
+            self._transport.last_ready_at = None
+        t0 = time.perf_counter()
+        with self._observe_l2():
+            out = self.manager.get_cache_tokens(tokens)
+        self.stats.fabric_get_s += time.perf_counter() - t0
+        self.stats.fabric_gets += 1
+        return out
+
     def fetch_pending(self, ready_at: float | None) -> bool:
         """True while a fetched payload is still in simulated flight."""
         return (ready_at is not None and self.clock is not None
                 and self.clock.now() < ready_at)
 
-    def wait_fetch(self, ready_at: float | None) -> float:
+    def wait_fetch(self, ready_at: float | None, *, rid=None) -> float:
         """Block until the clock passes ``ready_at`` -- the experienced
         part of an L2 flight the scheduler could not hide behind decode
         steps.  Returns virtual seconds waited."""
         if ready_at is None or self.clock is None:
             return 0.0
-        waited = self.clock.wait_until(ready_at)
+        with span("kv.flight_wait", rid=rid):
+            waited = self.clock.wait_until(ready_at)
         if waited > 0.0:
             self.stats.l2_wait_s += waited
             self.stats.l2_fetch_waits += 1
         return waited
 
-    def pages_async(self, payload: bytes, n_tokens: int):
+    def pages_async(self, payload: bytes, n_tokens: int, *, rid=None):
         """Fetch-ahead payload -> pages decode on the adapter worker.
 
         Under a quantized codec this is where the dequantize leg runs:
@@ -368,28 +376,39 @@ class TieredKVManager:
         requests did not experience."""
         def decode():
             t0 = time.perf_counter()
-            out = self.adapter.payload_to_pages(payload, n_tokens,
-                                                self.pool.page_size)
+            with span("restore.decode", rid=rid):
+                out = self.adapter.payload_to_pages(payload, n_tokens,
+                                                    self.pool.page_size)
             self.stats.dequant_overlap_s += time.perf_counter() - t0
             return out
 
         return self.adapter.run_async(decode)
 
-    def write_back_async(self, tokens: list[int]) -> None:
+    def write_back_async(self, tokens: list[int], *, rid=None) -> None:
         """Set KVC for a finished prefill *off* the decode loop: the
         block payload computation (one forward per uncached block) runs
         on the adapter's worker thread and the next lookup drains it, so
         write-back no longer stalls running decodes."""
         if self.manager is None:
             return
-        self._wb_future = self.adapter.run_async(
-            self.manager.add_blocks_tokens, tokens)
+
+        def write_back():
+            with span("write_back.blocks", rid=rid):
+                return self.manager.add_blocks_tokens(tokens)
+
+        self._wb_future = self.adapter.run_async(write_back)
 
     def write_back_sync(self, tokens: list[int]) -> None:
         if self.manager is not None:
             self.manager.add_blocks_tokens(tokens)
 
-    def drain_write_back(self) -> None:
-        if self._wb_future is not None:
+    def drain_write_back(self) -> float:
+        """Block on the in-flight Set KVC, if any; returns seconds
+        waited."""
+        if self._wb_future is None:
+            return 0.0
+        t0 = time.perf_counter()
+        with span("kv.write_back_wait"):
             self._wb_future.result()
-            self._wb_future = None
+        self._wb_future = None
+        return time.perf_counter() - t0

@@ -57,6 +57,7 @@ class GenerationResult:
     prefill_tokens: int         # tokens actually prefilled
     wall_time_s: float = 0.0
     ttft_s: float = 0.0         # queue-entry -> first token latency
+    queue_wait_s: float = 0.0   # queue-entry -> first admission to a slot
     finish_reason: str = FinishReason.MAX_NEW_TOKENS.value
     preemptions: int = 0        # times this sequence was swapped out
     tenant: str = ""            # copied from the request (SLO accounting)
@@ -78,6 +79,7 @@ class Seq:
     finish_reason: str = FinishReason.MAX_NEW_TOKENS.value
     enqueue_t: float = 0.0
     ttft_s: float = 0.0
+    queue_wait_s: float | None = None  # stamped at the first admission
     wall_s: float = 0.0
     # chunked-prefill state machine:
     reserve: int = 0                  # worst-case token footprint (park pos)
@@ -140,6 +142,7 @@ def seq_result(s: Seq, tokenizer) -> GenerationResult:
         prefill_tokens=len(s.tokens) - s.cached,
         wall_time_s=s.wall_s,
         ttft_s=s.ttft_s,
+        queue_wait_s=s.queue_wait_s or 0.0,
         finish_reason=s.finish_reason,
         preemptions=s.preempt_count,
         tenant=s.request.tenant,

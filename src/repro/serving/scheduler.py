@@ -50,6 +50,7 @@ from repro.serving.request import (
 from repro.serving.sampler import SamplingParams, stack_sampling
 from repro.serving.stats import EngineStats
 from repro.serving.tokenizer import truncate_prompt
+from repro.trace import span
 
 
 def head_span(n_tokens: int, cursor: int, budget: int) -> tuple[int, int]:
@@ -145,24 +146,34 @@ class Scheduler:
         """One scheduling round: drain the inbox, grow/admit, and run one
         fused device step if anything is live.  Returns whether backlog
         remains.  Single-threaded: only the worker loop or ``run`` may
-        call this."""
+        call this.  A round with nothing to do is neither spanned nor
+        counted."""
         self._drain_inbox()
-        # -- growth: running sequences claim next-write pages first -----
-        if self._active:
-            self._grow_active()
-        # -- admission: fill freed slots from the queue ------------------
-        self._admit()
-        if self._active or self._prefilling:
-            self._step_once()
-        elif self._pending:
-            # the machine is idle (every slot free, nothing to preempt)
-            # and the head still cannot admit: its footprint can never
-            # fit.  Fail that request alone; the stream continues.
-            s = self._pending.popleft()
-            self._fail_seq(s, RuntimeError(
-                "cannot admit request: KV page pool too small for "
-                f"a {self._need_tokens(s)}-token "
-                "footprint even with every slot preempted"))
+        if not self.backlog:
+            return False
+        t0 = time.perf_counter()
+        with span("sched.round"):
+            # -- growth: running sequences claim next-write pages first --
+            if self._active:
+                self._grow_active()
+            # -- admission: fill freed slots from the queue ---------------
+            if self._pending:
+                with span("sched.admit"):
+                    self._admit()
+            if self._active or self._prefilling:
+                self._step_once()
+            elif self._pending:
+                # the machine is idle (every slot free, nothing to
+                # preempt) and the head still cannot admit: its footprint
+                # can never fit.  Fail that request alone; the stream
+                # continues.
+                s = self._pending.popleft()
+                self._fail_seq(s, RuntimeError(
+                    "cannot admit request: KV page pool too small for "
+                    f"a {self._need_tokens(s)}-token "
+                    "footprint even with every slot preempted"))
+        self.stats.rounds += 1
+        self.stats.round_s += time.perf_counter() - t0
         return self.backlog
 
     def _drain_inbox(self) -> None:
@@ -234,35 +245,45 @@ class Scheduler:
     # one fused device step + host bookkeeping
     # ------------------------------------------------------------------
     def _step_once(self) -> None:
-        b = self.max_batch
-        chunk = self._plan_chunk()
+        with span("sched.plan_chunk"):
+            chunk = self._plan_chunk()
 
-        if self._samp_dirty:
-            self._samp_dev = stack_sampling(self._samp)
-            self._mode = self.ex.sampler_mode(self._samp)
-            self._samp_dirty = False
-        if self._bt_dirty:
-            # contiguous slot regions need no table on device; free-list
-            # pools upload the table only when admission/release/growth
-            # changed it
-            self._bt_dev = (None if self.kv.pool.contiguous
-                            else jnp.asarray(self.kv.pool.block_tables))
-            self._bt_dirty = False
-        len_d = jnp.asarray(self._lengths)
-        tok_d = jnp.asarray(self._tokens)
+        with span("exec.dispatch"):
+            if self._samp_dirty:
+                self._samp_dev = stack_sampling(self._samp)
+                self._mode = self.ex.sampler_mode(self._samp)
+                self._samp_dirty = False
+            if self._bt_dirty:
+                # contiguous slot regions need no table on device;
+                # free-list pools upload the table only when admission/
+                # release/growth changed it
+                self._bt_dev = (None if self.kv.pool.contiguous
+                                else jnp.asarray(self.kv.pool.block_tables))
+                self._bt_dirty = False
+            len_d = jnp.asarray(self._lengths)
+            tok_d = jnp.asarray(self._tokens)
 
-        # -- one fused device step; ONE host sync (the token read) ------
-        t0 = time.perf_counter()
-        temps_d, tks_d, tps_d = self._samp_dev
-        ops_c = None if chunk is None else chunk[4]
-        nxt = self.ex.step(self._bt_dev, len_d, tok_d, temps_d, tks_d,
-                           tps_d, self._mode, chunk_ops=ops_c)
-        nxt_h = np.asarray(nxt)               # the step's single host sync
+            # -- one fused device step; ONE host sync (the token read) --
+            t0 = time.perf_counter()
+            temps_d, tks_d, tps_d = self._samp_dev
+            ops_c = None if chunk is None else chunk[4]
+            nxt = self.ex.step(self._bt_dev, len_d, tok_d, temps_d, tks_d,
+                               tps_d, self._mode, chunk_ops=ops_c)
+        t_sync = time.perf_counter()
+        with span("exec.sync"):
+            nxt_h = np.asarray(nxt)           # the step's single host sync
         now = time.perf_counter()
+        self.stats.step_sync_s += now - t_sync
         self.stats.decode_time_s += now - t0
         self.stats.decode_steps += 1
+        self.stats.mixed_steps += chunk is not None
+        with span("sched.book"):
+            self._book_step(nxt_h, chunk, now)
 
-        # -- host-side scheduling on the synced token ids ---------------
+    def _book_step(self, nxt_h: np.ndarray, chunk, now: float) -> None:
+        """Host-side scheduling on a step's synced token ids: emitted
+        tokens, finished sequences, and the retired chunk."""
+        b = self.max_batch
         in_admission = bool(self._prefilling) or self._admit_stall
         self._admit_stall = False
         for slot, s in list(self._active.items()):
@@ -394,6 +415,8 @@ class Scheduler:
         s.admit_seq = self._admit_counter
         if self._active or self._prefilling:
             self.stats.mid_decode_admissions += 1
+        if s.queue_wait_s is None:
+            s.queue_wait_s = time.perf_counter() - s.enqueue_t
         if s.state is SeqState.PREEMPTED:
             self._restore(s, slot)
         return s, slot
@@ -482,11 +505,7 @@ class Scheduler:
             if s.pages_future is not None:
                 # a fetched prefix is still in flight: land it first so
                 # the export below covers everything the cursor claims
-                self.kv.wait_fetch(s.fetch_ready_at)
-                s.fetch_ready_at = None
-                k_blocks, v_blocks = s.pages_future.result()
-                s.pages_future = None
-                self.kv.pool.write_pages(slot, 0, k_blocks, v_blocks)
+                self._land_prefix(s, slot)
             if s.cursor > 0:
                 self.kv.offload(s.request.request_id, slot,
                                 s.prefill_tokens[: s.cursor])
@@ -542,9 +561,7 @@ class Scheduler:
         saw_flight = False
         for slot, s in list(self._prefilling.items()):
             if not s.looked_up:
-                t0 = time.perf_counter()
                 self._lookup_and_prefetch(s)
-                self.stats.prefill_time_s += time.perf_counter() - t0
             if s.pages_future is not None and (
                     self.kv.fetch_pending(s.fetch_ready_at)
                     or not s.pages_future.done()):
@@ -567,11 +584,7 @@ class Scheduler:
             chosen = deferred
         slot, s = chosen
         if s.pages_future is not None:
-            self.kv.wait_fetch(s.fetch_ready_at)
-            s.fetch_ready_at = None
-            k_blocks, v_blocks = s.pages_future.result()
-            s.pages_future = None
-            self.kv.pool.write_pages(slot, 0, k_blocks, v_blocks)
+            self._land_prefix(s, slot)
         toks = s.prefill_tokens
         n = len(toks)
         start, v = head_span(n, s.cursor, self.chunk_tokens)
@@ -611,7 +624,6 @@ class Scheduler:
         their first tokens sampled in one call with one host sync, while
         resumed sequences re-enter decode with their carried next token.
         """
-        t0 = time.perf_counter()
         for s, slot in admitted:
             s.state = SeqState.PREFILLING
             if s.replay_next is not None:
@@ -624,11 +636,7 @@ class Scheduler:
                 # cold start: nothing is decoding, so the fetch flights
                 # cannot hide -- wait them out (clock is monotone, so the
                 # wave's total wait is the max remaining flight)
-                self.kv.wait_fetch(s.fetch_ready_at)
-                s.fetch_ready_at = None
-                k_blocks, v_blocks = s.pages_future.result()
-                s.pages_future = None
-                self.kv.pool.write_pages(slot, 0, k_blocks, v_blocks)
+                self._land_prefix(s, slot)
 
         last_logits: dict[int, jnp.ndarray] = {}
         live = [(s, slot) for s, slot in admitted]
@@ -652,7 +660,9 @@ class Scheduler:
                 bts[i] = self.kv.pool.table_row(slot)
                 self.kv.pool.note_span(slot, start, v)
                 self.chunk_log.append((slot, start, v))
-            lg = self.ex.chunk_wave(buf, bts, offs, valids)
+            with span("exec.dispatch"):
+                lg = self.ex.chunk_wave(buf, bts, offs, valids)
+            self.stats.chunk_waves += 1
             self.stats.prefill_chunks += 1
             nxt_live = []
             for i, (s, slot) in enumerate(live):
@@ -664,7 +674,6 @@ class Scheduler:
                     nxt_live.append((s, slot))
             live = nxt_live
 
-        self.stats.prefill_time_s += time.perf_counter() - t0
         now = time.perf_counter()
         fresh = [(s, slot) for s, slot in admitted
                  if s.replay_next is None]
@@ -674,7 +683,7 @@ class Scheduler:
         if not fresh:
             return
         # first tokens for the wave: one sample call, one host sync
-        tids = self.ex.sample_first(
+        tids = self._sample_first(
             [last_logits[id(s)] for s, _ in fresh],
             [s.request.sampling for s, _ in fresh])
         now = time.perf_counter()
@@ -697,7 +706,6 @@ class Scheduler:
         discarded -- the next token is already known).  First tokens for
         the wave's fresh members are sampled in one call with one host
         sync."""
-        t0 = time.perf_counter()
         last_logits: list = []
         fresh: list[tuple[Seq, int]] = []
         sampled: list[tuple[Seq, int]] = []
@@ -761,7 +769,6 @@ class Scheduler:
                     last_logits[j] = fresh_logits[fi]
                     fi += 1
 
-        self.stats.prefill_time_s += time.perf_counter() - t0
         now = time.perf_counter()
         for s, slot in resumed:
             self._resume_active(s, slot, now)
@@ -769,7 +776,7 @@ class Scheduler:
             return
         # first tokens for the wave from the prefill logits: one sample
         # call, one host sync (at admission, not in the decode loop)
-        tids = self.ex.sample_first(
+        tids = self._sample_first(
             last_logits, [s.request.sampling for s, _ in sampled])
         now = time.perf_counter()
         for (s, slot), tid in zip(sampled, tids):
@@ -799,18 +806,16 @@ class Scheduler:
         block and replays only the final token (the chunk machinery
         handles the one-token, unaligned-start span)."""
         n = len(s.tokens)
-        self.kv.wait_fetch(s.fetch_ready_at)
-        s.fetch_ready_at = None
-        k_blocks, v_blocks = s.pages_future.result()
-        s.pages_future = None
-        self.kv.pool.write_pages(slot, 0, k_blocks, v_blocks)
+        self._land_prefix(s, slot)
         start = s.cursor
         v = n - start
         self.kv.pool.note_span(slot, start, v)
         self.chunk_log.append((slot, start, v))
         toks = np.asarray(s.tokens[start:], np.int32)[None]
         bt_row = np.asarray(self.kv.pool.table_row(slot)[None], np.int32)
-        return self.ex.prefill_chunk_one(toks, bt_row, start, v)
+        self.stats.chunk_waves += 1
+        with span("exec.dispatch"):
+            return self.ex.prefill_chunk_one(toks, bt_row, start, v)
 
     def _replay_tail(self, s: Seq, slot: int) -> None:
         """Restore replay, stop-the-world flavor: the tokens past the
@@ -824,13 +829,38 @@ class Scheduler:
         self.chunk_log.append((slot, start, v))
         buf = np.asarray(toks[start:], np.int32)[None]
         bt_row = np.asarray(self.kv.pool.table_row(slot)[None], np.int32)
-        self.ex.prefill_chunk_one(buf, bt_row, start, v)
+        with span("exec.dispatch"):
+            self.ex.prefill_chunk_one(buf, bt_row, start, v)
+        self.stats.chunk_waves += 1
         self.stats.prefill_chunks += 1
         s.cursor = len(toks)
 
     # ------------------------------------------------------------------
     # shared helpers
     # ------------------------------------------------------------------
+    def _land_prefix(self, s: Seq, slot: int) -> None:
+        """Land a looked-up prefix in ``slot``: wait out what is left of
+        its flight, then of its decode on the fetch-ahead worker, and
+        import the pages."""
+        rid = s.request.request_id
+        self.kv.wait_fetch(s.fetch_ready_at, rid=rid)
+        s.fetch_ready_at = None
+        t0 = time.perf_counter()
+        with span("kv.restore_wait", rid=rid):
+            k_blocks, v_blocks = s.pages_future.result()
+        self.stats.restore_wait_s += time.perf_counter() - t0
+        s.pages_future = None
+        with span("kv.page_import", rid=rid):
+            self.kv.pool.write_pages(slot, 0, k_blocks, v_blocks)
+
+    def _sample_first(self, logits_rows, samplings) -> np.ndarray:
+        """First tokens of an admission wave: its one host sync."""
+        t0 = time.perf_counter()
+        with span("exec.sync"):
+            tids = self.ex.sample_first(logits_rows, samplings)
+        self.stats.step_sync_s += time.perf_counter() - t0
+        return tids
+
     def _lookup_and_prefetch(self, s: Seq) -> None:
         """Prefix sources for a fresh admission, best tier first: the
         host page cache may hold this request's pages from a prefill-time
@@ -860,11 +890,12 @@ class Scheduler:
                 s.cached = cached
                 s.cursor = cached
                 s.fetch_ready_at = ready_at
-                s.pages_future = self.kv.pages_async(payload, restore)
+                s.pages_future = self.kv.pages_async(
+                    payload, restore, rid=s.request.request_id)
         if self.kv.write_back and self.kv.manager is not None:
             # Set KVC for uncached blocks on the worker thread (a no-op
             # radix probe when the lookup fully hit)
-            self.kv.write_back_async(s.tokens)
+            self.kv.write_back_async(s.tokens, rid=s.request.request_id)
 
     def _finish_prefill(self, s: Seq, slot: int, tid: int,
                         now: float) -> None:

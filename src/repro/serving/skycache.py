@@ -26,6 +26,7 @@ from __future__ import annotations
 
 from typing import Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -36,6 +37,7 @@ from repro.core.chunking import (
 )
 from repro.core.hashing import chain_hashes
 from repro.models.model import Model
+from repro.trace import span
 
 
 class SkyKVCAdapter:
@@ -227,22 +229,33 @@ class SkyKVCAdapter:
         toks = jnp.asarray(list(tokens), jnp.int32)[None]
         if past is None or past_len == 0:
             past_len = 0
-            _, _, state = self.model.forward(
-                self.params, toks, collect_state=True
-            )
+            prefix = None
         else:
-            prefix = self.payload_to_state(past)
-            _, _, state = self.model.forward(
-                self.params, toks[:, past_len:],
-                q_offset=past_len, prefix_state=prefix, collect_state=True,
-            )
-            state = _concat_prefix(self.cfg, prefix, state, past_len)
+            with span("write_back.resume"):
+                prefix = self.payload_to_state(past)
+        # the forward is eager: its span waits for the device, so the
+        # encode after it times the host's read-back and codec alone
+        with span("write_back.forward"):
+            if prefix is None:
+                _, _, state = self.model.forward(
+                    self.params, toks, collect_state=True
+                )
+            else:
+                _, _, state = self.model.forward(
+                    self.params, toks[:, past_len:],
+                    q_offset=past_len, prefix_state=prefix,
+                    collect_state=True,
+                )
+                state = _concat_prefix(self.cfg, prefix, state, past_len)
+            jax.block_until_ready(state)
         prev_hash = None
         if self.codec.delta and self._delta_ok and past_len > 0:
             prev_hash = chain_hashes(
                 list(tokens[:past_len]), self.codec.block_tokens)[-1]
-        return self.state_to_payload(state, len(tokens),
-                                     past_len=past_len, prev_hash=prev_hash)
+        with span("write_back.encode"):
+            return self.state_to_payload(state, len(tokens),
+                                         past_len=past_len,
+                                         prev_hash=prev_hash)
 
 
 def _concat_prefix(cfg, prefix: dict, state: dict, past_len: int) -> dict:

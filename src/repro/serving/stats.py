@@ -75,11 +75,25 @@ class EngineStats:
     cached_tokens: int = 0
     prefilled_tokens: int = 0
     decoded_tokens: int = 0
-    prefill_time_s: float = 0.0
     decode_time_s: float = 0.0
     decode_steps: int = 0             # jitted step programs launched
+    mixed_steps: int = 0              # ... of them carrying a prefill chunk
+    chunk_waves: int = 0              # chunk-wave programs launched
     mid_decode_admissions: int = 0    # requests admitted into a live batch
     prefill_chunks: int = 0           # chunk programs fused into steps
+    # the serving loop's wall clock at the boundaries of its spans
+    # (repro.trace): scheduling rounds that had work, the part of them
+    # blocked reading a step's or wave's tokens back, and the waits an
+    # admission pays on the fetch-ahead worker -- the write-back drained
+    # before a lookup and the restored prefix's decode
+    rounds: int = 0
+    round_s: float = 0.0
+    step_sync_s: float = 0.0
+    write_back_wait_s: float = 0.0
+    restore_wait_s: float = 0.0
+    # Get KVC calls into the constellation and their wall seconds
+    fabric_gets: int = 0
+    fabric_get_s: float = 0.0
     # tiered-KV swap activity (preemption-by-offload):
     preemptions: int = 0              # sequences offloaded out of the pool
     restores: int = 0                 # preempted sequences brought back

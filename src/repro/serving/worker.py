@@ -19,6 +19,7 @@ from collections import deque
 from concurrent.futures import Future, InvalidStateError
 
 from repro.serving.request import Request
+from repro.trace import span
 
 
 class StreamWorker:
@@ -153,7 +154,8 @@ class StreamWorker:
                 # idle: settle pending Set KVC, then sleep until work
                 if self.engine.paged:
                     self.engine.kv.drain_write_back()
-                self._wake.wait(0.005)
+                with span("loop.idle"):
+                    self._wake.wait(0.005)
                 self._wake.clear()
             if self.engine.paged:
                 self.engine.kv.drain_write_back()
