@@ -11,7 +11,9 @@ Set/Get KVC over the simulated 19x5 constellation.  Phases:
 1. device: refuses to run unless JAX's first device is a TPU and
    ``REPRO_KERNEL_IMPL`` is unset (no oracle may stand in for a kernel);
 2. kernels: the four Pallas kernels of the served path, compiled, at the
-   served shapes, against their ``kernels/ref.py`` oracles;
+   served shapes, against their ``kernels/ref.py`` oracles -- the paged
+   ones reading a stacked pool ``[L, N, page, Hkv, D]`` in place at its
+   middle and last layer, as the step programs do, and one layer's pool;
 3. closed batch: an ``EngineCluster`` of 2 replicas with block-table
    pools that together take over half of the HBM left free by the
    parameters serves 8 greedy requests sharing 128-token document blocks;
@@ -141,7 +143,9 @@ def kernels_phase(size: Size, *, compiled: bool) -> None:
         return jnp.asarray(rng.standard_normal(shape, np.float32), dt)
 
     n_pages = b * p + 1
-    kp, vp = rand(n_pages, page, hkv, d), rand(n_pages, page, hkv, d)
+    layers = cfg.num_layers
+    mid, last = layers // 2, layers - 1
+    kp, vp = (rand(layers, n_pages, page, hkv, d) for _ in range(2))
     bt = jnp.asarray(rng.permutation(n_pages)[:b * p].reshape(b, p),
                      jnp.int32)
     edge = [0, 1, 2, page - 1, page, page + 1,
@@ -157,34 +161,38 @@ def kernels_phase(size: Size, *, compiled: bool) -> None:
     prefix = (size.doc_blocks - 1) * page
     interpret = not compiled
 
+    # the pools are arguments, not constants folded into the programs
     cases = {
         "paged_attention[block_table]": (
-            lambda: paged_attention(q, kp, vp, lens, block_tables=bt,
-                                    interpret=interpret),
-            lambda: ref.paged_attention_ref(q, kp, vp, lens,
-                                            block_tables=bt)),
+            lambda kp, vp: paged_attention(q, kp, vp, lens, block_tables=bt,
+                                           layer=last, interpret=interpret),
+            lambda kp, vp: ref.paged_attention_ref(
+                q, kp[last], vp[last], lens, block_tables=bt)),
         "paged_attention[contiguous]": (
-            lambda: paged_attention(q, kp[bt], vp[bt], lens,
-                                    interpret=interpret),
-            lambda: ref.paged_attention_ref(q, kp[bt], vp[bt], lens)),
+            lambda kp, vp: paged_attention(q, kp[mid][bt], vp[mid][bt], lens,
+                                           interpret=interpret),
+            lambda kp, vp: ref.paged_attention_ref(
+                q, kp[mid][bt], vp[mid][bt], lens)),
         "chunked_prefill_paged[chunk]": (
-            lambda: chunked_prefill_paged(qc, kp, vp, offs + chunk, bt[:2],
-                                          offs, interpret=interpret),
-            lambda: ref.chunked_prefill_paged_ref(qc, kp, vp, offs + chunk,
-                                                  bt[:2], offs)),
+            lambda kp, vp: chunked_prefill_paged(
+                qc, kp, vp, offs + chunk, bt[:2], offs, layer=mid,
+                interpret=interpret),
+            lambda kp, vp: ref.chunked_prefill_paged_ref(
+                qc, kp[mid], vp[mid], offs + chunk, bt[:2], offs)),
         "chunked_prefill_paged[replay]": (
-            lambda: chunked_prefill_paged(q1, kp, vp, off1 + 1, bt[:1],
-                                          off1, interpret=interpret),
-            lambda: ref.chunked_prefill_paged_ref(q1, kp, vp, off1 + 1,
-                                                  bt[:1], off1)),
+            lambda kp, vp: chunked_prefill_paged(
+                q1, kp, vp, off1 + 1, bt[:1], off1, layer=last,
+                interpret=interpret),
+            lambda kp, vp: ref.chunked_prefill_paged_ref(
+                q1, kp[last], vp[last], off1 + 1, bt[:1], off1)),
         "chunked_prefill_attention": (
-            lambda: chunked_prefill_attention(qd, kd, vd, q_offset=prefix,
-                                              interpret=interpret),
-            lambda: ref.attention_ref(qd, kd, vd, q_offset=prefix)),
+            lambda kp, vp: chunked_prefill_attention(
+                qd, kd, vd, q_offset=prefix, interpret=interpret),
+            lambda kp, vp: ref.attention_ref(qd, kd, vd, q_offset=prefix)),
     }
     for name, (kernel, oracle) in cases.items():
-        got = np.asarray(jax.jit(kernel)(), np.float32)
-        want = np.asarray(jax.jit(oracle)(), np.float32)
+        got = np.asarray(jax.jit(kernel)(kp, vp), np.float32)
+        want = np.asarray(jax.jit(oracle)(kp, vp), np.float32)
         err = float(np.abs(got - want).max())
         ok = bool(np.allclose(got, want, **KERNEL_TOL))
         _say("kernels", f"{name} {tuple(got.shape)} {dt.name}: "

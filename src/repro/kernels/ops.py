@@ -68,6 +68,7 @@ def paged_attention(
     q, k_pages, v_pages, lengths, *,
     softmax_scale: float | None = None,
     block_tables=None,
+    layer=None,
     grouped: bool | None = None,
     impl: str = "auto",
 ):
@@ -75,12 +76,16 @@ def paged_attention(
 
     ``block_tables`` [B,P] switches to the shared-pool layout: k/v are
     [N,page,Hkv,D] and pages are resolved per sequence through the table
-    (the serving engine's device-resident layout).  ``grouped`` forces the
-    jnp oracle's grouped-GQA contraction (no head-repeat materialization);
-    the Pallas kernel is always grouped by construction.
+    (the serving engine's device-resident layout); with ``layer`` they
+    are the stacked pool [L,N,page,Hkv,D], read at that layer in place.
+    ``grouped`` forces the jnp oracle's grouped-GQA contraction (no
+    head-repeat materialization); the Pallas kernel is always grouped by
+    construction.
     """
     impl = _resolve(impl)
     if impl == "jnp":
+        if layer is not None:
+            k_pages, v_pages = k_pages[layer], v_pages[layer]
         return ref.paged_attention_ref(
             q, k_pages, v_pages, lengths, softmax_scale=softmax_scale,
             block_tables=block_tables, grouped=grouped,
@@ -89,18 +94,20 @@ def paged_attention(
 
     return pa.paged_attention(
         q, k_pages, v_pages, lengths,
-        softmax_scale=softmax_scale, block_tables=block_tables,
+        softmax_scale=softmax_scale, block_tables=block_tables, layer=layer,
         interpret=_interpret(),
     )
 
 
 def chunked_prefill_paged(
     q, k_pool, v_pool, lengths, block_tables, q_offsets, *,
+    layer=None,
     softmax_scale: float | None = None,
     impl: str = "auto",
 ):
     """Prefill-chunk attention over a shared page pool ([B,Sq,H,D] x
-    [N,page,Hkv,D] through [B,P] block tables).
+    [N,page,Hkv,D] through [B,P] block tables; with ``layer``, the stacked
+    pool [L,N,page,Hkv,D] read at that layer).
 
     The serving engine's chunked-prefill read path: a chunk's queries at
     absolute offset ``q_offsets`` attend causally over the first
@@ -111,6 +118,8 @@ def chunked_prefill_paged(
     """
     impl = _resolve(impl)
     if impl == "jnp":
+        if layer is not None:
+            k_pool, v_pool = k_pool[layer], v_pool[layer]
         return ref.chunked_prefill_paged_ref(
             q, k_pool, v_pool, lengths, block_tables, q_offsets,
             softmax_scale=softmax_scale,
@@ -118,7 +127,7 @@ def chunked_prefill_paged(
     from repro.kernels import chunked_prefill
 
     return chunked_prefill.chunked_prefill_paged(
-        q, k_pool, v_pool, lengths, block_tables, q_offsets,
+        q, k_pool, v_pool, lengths, block_tables, q_offsets, layer=layer,
         softmax_scale=softmax_scale, interpret=_interpret(),
     )
 

@@ -86,8 +86,9 @@ def attention_prefill_paged(
     x,                     # [R, C, d_model] one prefill chunk per row
     cfg: ModelConfig,
     *,
-    k_pool,                # [N_pages, page, Hkv, hd] shared page pool
+    k_pool,                # [L, N_pages, page, Hkv, hd] stacked page pool
     v_pool,
+    layer,                 # this layer's index into the pools
     block_tables,          # [R, P] page ids of each row's slot
     q_offsets,             # [R] int32: chunk starts (absolute positions)
     n_valid,               # [R] int32: valid tokens per chunk (<= C)
@@ -95,16 +96,17 @@ def attention_prefill_paged(
     """A batch of prefill chunks against the shared page pool; returns
     ``(out, k_pool', v_pool')``.
 
-    Each row's chunk K/V are written into its slot's pages *first*
-    (a chunk start need not be page-aligned -- the whole-prompt-cached
-    replay starts one token before a block boundary), then the chunk's
-    queries attend over everything valid so far: SkyMemory-restored
-    pages, earlier chunks, and this chunk, all read in place through the
-    block tables.  Positions past ``n_valid`` (the padded tail of a
-    ragged final chunk, or an all-padding batch row) are left out of the
-    write and their outputs are garbage the scheduler never reads.
-    ``q_offsets`` / ``n_valid`` are traced values: one compilation per
-    chunk-buffer shape serves every chunk of every admission.
+    Each row's chunk K/V are written into its slot's pages of ``layer``
+    *first* (a chunk start need not be page-aligned -- the
+    whole-prompt-cached replay starts one token before a block boundary),
+    then the chunk's queries attend over everything valid so far:
+    SkyMemory-restored pages, earlier chunks, and this chunk, all read in
+    place through the block tables.  Positions past ``n_valid`` (the
+    padded tail of a ragged final chunk, or an all-padding batch row) are
+    left out of the write and their outputs are garbage the scheduler
+    never reads.  ``q_offsets`` / ``n_valid`` are traced values: one
+    compilation per chunk-buffer shape serves every chunk of every
+    admission.
     """
     r, c = x.shape[0], x.shape[1]
     h, hd = cfg.num_heads, cfg.head_dim
@@ -122,15 +124,14 @@ def attention_prefill_paged(
     int8_kvc = k_pool.dtype == jnp.int8
     if int8_kvc:
         k_new, v_new = _quant(k_new), _quant(v_new)
-    k_pool = _write_chunks(k_pool, k_new, block_tables, q_offsets, n_valid)
-    v_pool = _write_chunks(v_pool, v_new, block_tables, q_offsets, n_valid)
-    if int8_kvc:
-        k_read = _dequant(k_pool, x.dtype)
-        v_read = _dequant(v_pool, x.dtype)
-    else:
-        k_read, v_read = k_pool, v_pool
+    k_pool = _write_chunks(k_pool, k_new, layer, block_tables, q_offsets,
+                           n_valid)
+    v_pool = _write_chunks(v_pool, v_new, layer, block_tables, q_offsets,
+                           n_valid)
+    k_read, v_read, read_layer = _readable(k_pool, v_pool, layer, x.dtype)
     out = ops.chunked_prefill_paged(
         q, k_read, v_read, q_offsets + n_valid, block_tables, q_offsets,
+        layer=read_layer,
     )
     return out.reshape(r, c, h * hd) @ params["wo"], k_pool, v_pool
 
@@ -200,8 +201,9 @@ def attention_decode_paged(
     x,                     # [B, 1, d_model]
     cfg: ModelConfig,
     *,
-    k_pool,                # [N_pages, page, Hkv, hd] shared page pool
+    k_pool,                # [L, N_pages, page, Hkv, hd] stacked page pool
     v_pool,
+    layer,                 # this layer's index into the pools
     block_tables,          # [B, P] page ids per slot; None in contiguous mode
     lengths,               # [B] int32: tokens already cached per sequence
     contiguous: bool = False,
@@ -210,20 +212,19 @@ def attention_decode_paged(
 
     Per-sequence positions are heterogeneous (slots admit mid-decode), so
     RoPE, the page write, and the attention mask are all driven by
-    ``lengths``.  The new K/V is written into the page holding position
-    ``lengths[b]`` -- pages are exclusive to a slot, so the rows' writes
-    never collide (idle slots write into their own region / the scratch
-    page, which the next admission overwrites).
+    ``lengths``.  The new K/V is written into the page of ``layer``
+    holding position ``lengths[b]`` -- pages are exclusive to a slot, so
+    the rows' writes never collide (idle slots write into their own
+    region / the scratch page, which the next admission overwrites).
 
     ``contiguous`` (slot-region pools): slot ``b`` owns pages
-    ``[b*P, (b+1)*P)``, so the page id is arithmetic and attention reads
-    the pool as ``[B, P, page, Hkv, hd]`` by reshape -- zero gather and no
-    table on device.  Otherwise pages resolve through ``block_tables``
-    (the scalar-prefetch kernel path).
+    ``[b*P, (b+1)*P)``, so the block table is arithmetic, made inside
+    the program (none is uploaded).  Either way attention reads the pool
+    in place through the table (the scalar-prefetch kernel path).
     """
     b = x.shape[0]
     h, hd = cfg.num_heads, cfg.head_dim
-    page = k_pool.shape[1]
+    page = k_pool.shape[2]
     pos = jnp.asarray(lengths, jnp.int32)                  # [B]
 
     q, k_new, v_new = _project_qkv(params, x, x, cfg)
@@ -235,72 +236,79 @@ def attention_decode_paged(
     v_new = maybe_shard(v_new, "decode_qkv")
 
     if contiguous:
-        p_max = k_pool.shape[0] // b
-        page_ids = jnp.arange(b, dtype=jnp.int32) * p_max + pos // page
-    else:
-        page_ids = jnp.take_along_axis(
-            block_tables, (pos // page)[:, None], axis=1)[:, 0]  # [B]
+        p_max = k_pool.shape[1] // b
+        block_tables = jnp.arange(b * p_max, dtype=jnp.int32).reshape(
+            b, p_max)
+    page_ids = jnp.take_along_axis(
+        block_tables, (pos // page)[:, None], axis=1)[:, 0]  # [B]
     slots = pos % page
     int8_kvc = k_pool.dtype == jnp.int8
     if int8_kvc:
         k_new, v_new = _quant(k_new), _quant(v_new)
-    k_pool = _write_tokens(k_pool, k_new[:, 0], page_ids, slots)
-    v_pool = _write_tokens(v_pool, v_new[:, 0], page_ids, slots)
-    if int8_kvc:
-        k_read = _dequant(k_pool, x.dtype)
-        v_read = _dequant(v_pool, x.dtype)
-    else:
-        k_read, v_read = k_pool, v_pool
-    if contiguous:
-        hkv = k_read.shape[2]
-        shape = (b, k_read.shape[0] // b, page, hkv, k_read.shape[3])
-        out = ops.paged_attention(
-            q[:, 0], k_read.reshape(shape), v_read.reshape(shape), pos + 1,
-            grouped=True,
-        )
-    else:
-        out = ops.paged_attention(
-            q[:, 0], k_read, v_read, pos + 1, block_tables=block_tables
-        )
+    k_pool = _write_tokens(k_pool, k_new[:, 0], layer, page_ids, slots)
+    v_pool = _write_tokens(v_pool, v_new[:, 0], layer, page_ids, slots)
+    k_read, v_read, read_layer = _readable(k_pool, v_pool, layer, x.dtype)
+    out = ops.paged_attention(
+        q[:, 0], k_read, v_read, pos + 1, block_tables=block_tables,
+        layer=read_layer,
+    )
     return out.reshape(b, 1, h * hd) @ params["wo"], k_pool, v_pool
 
 
-# Pool writes are row-by-row dynamic_update_slices, not scatters: they
-# update the pool in place in whatever layout the device keeps it, where a
-# scatter makes XLA re-lay the pool's layer out around it.
+def _readable(k_pool, v_pool, layer, dtype):
+    """What attention reads: the stacked pools at ``layer``, in place; an
+    int8 pool's layer dequantized to ``dtype`` (one layer's pool, read
+    with ``layer=None``)."""
+    if k_pool.dtype != jnp.int8:
+        return k_pool, v_pool, layer
+    return _dequant(k_pool[layer], dtype), _dequant(v_pool[layer], dtype), None
 
-def _write_tokens(pool, new, page_ids, slots):
-    """One token per row: ``new[b]`` [Hkv, hd] at ``(page_ids[b],
-    slots[b])`` of ``pool`` [N_pages, page, Hkv, hd]."""
+
+# Pool writes are row-by-row dynamic_update_slices into the stacked pool,
+# not scatters: they update the pool in place in whatever layout the
+# device keeps it, where a scatter makes XLA re-lay the pool out around it.
+
+def _write_tokens(pool, new, layer, page_ids, slots):
+    """One token per row: ``new[b]`` [Hkv, hd] at ``(layer, page_ids[b],
+    slots[b])`` of ``pool`` [L, N_pages, page, Hkv, hd]."""
     for b in range(new.shape[0]):
         pool = jax.lax.dynamic_update_slice(
-            pool, new[b][None, None].astype(pool.dtype),
-            (page_ids[b], slots[b], 0, 0))
+            pool, new[b][None, None, None].astype(pool.dtype),
+            (layer, page_ids[b], slots[b], 0, 0))
     return pool
 
 
-def _write_chunks(pool, new, block_tables, q_offsets, n_valid):
+def _write_chunks(pool, new, layer, block_tables, q_offsets, n_valid):
     """Row ``r``'s first ``n_valid[r]`` tokens of ``new`` [R, C, Hkv, hd]
-    at absolute positions ``q_offsets[r] + i``, through its block-table
-    row.  A chunk need not start on a page boundary, so each page it can
-    touch is read, blended with the chunk's tokens and written back whole;
-    positions outside ``[q_offsets, q_offsets + n_valid)`` keep what the
-    page held (an all-padding row rewrites its pages unchanged)."""
+    at absolute positions ``q_offsets[r] + i`` of ``layer``, through its
+    block-table row.  A chunk need not start on a page boundary, so each
+    page it can touch is read, blended with the chunk's tokens and
+    written back whole; positions outside ``[q_offsets, q_offsets +
+    n_valid)`` keep what the page held (an all-padding row rewrites its
+    pages unchanged).  Pages are read and written one after another, so
+    a page reached twice keeps both writes; what goes into each page is
+    worked out for all of them at once, which keeps the traced program
+    small."""
     r, c = new.shape[:2]
-    page = pool.shape[1]
+    page = pool.shape[2]
     num_tables = block_tables.shape[1]
     span = (c + page - 2) // page + 1       # pages C tokens can straddle
+    p_idx = q_offsets[:, None] // page + jnp.arange(span)        # [R, S]
+    t = (p_idx[..., None] * page + jnp.arange(page)
+         - q_offsets[:, None, None])                             # [R, S, page]
+    valid = ((t >= 0) & (t < n_valid[:, None, None])
+             & (p_idx < num_tables)[..., None])[..., None, None]
+    pids = jnp.take_along_axis(block_tables,
+                               jnp.minimum(p_idx, num_tables - 1), axis=1)
+    vals = jnp.take_along_axis(
+        new, jnp.clip(t, 0, c - 1).reshape(r, span * page, 1, 1), axis=1)
+    vals = vals.reshape(valid.shape[:3] + new.shape[2:]).astype(pool.dtype)
     for i in range(r):
         for j in range(span):
-            p_idx = q_offsets[i] // page + j
-            t = p_idx * page + jnp.arange(page, dtype=jnp.int32) - q_offsets[i]
-            valid = (t >= 0) & (t < n_valid[i]) & (p_idx < num_tables)
-            pid = block_tables[i, jnp.minimum(p_idx, num_tables - 1)]
-            old = jax.lax.dynamic_slice_in_dim(pool, pid, 1, axis=0)[0]
-            vals = jnp.take(new[i], jnp.clip(t, 0, c - 1), axis=0)
-            blended = jnp.where(valid[:, None, None],
-                                vals.astype(pool.dtype), old)
-            pool = jax.lax.dynamic_update_index_in_dim(pool, blended, pid, 0)
+            at = (layer, pids[i, j], 0, 0, 0)
+            old = jax.lax.dynamic_slice(pool, at, (1, 1) + pool.shape[2:])
+            pool = jax.lax.dynamic_update_slice(
+                pool, jnp.where(valid[i, j], vals[i, j], old), at)
     return pool
 
 

@@ -505,16 +505,16 @@ class Model:
         cfg = self.cfg
         x = embed_tokens(params["embed"], tokens, cfg)
 
-        # The stacked pools ride the scan CARRY and each layer writes back
-        # through dynamic_update_index_in_dim, so XLA aliases the update in
-        # place -- a ys-stacked scan would materialize a full copy of the
-        # cache every token (the dominant memory traffic of a decode step).
+        # The stacked pools ride the scan CARRY whole: each layer writes its
+        # tokens into them in place and attention reads its layer's pages
+        # straight from them -- no layer is sliced out or written back, and
+        # a ys-stacked scan would materialize a full copy of the cache.
         def body(carry, l):
             x, kp, vp = carry
             p = jax.tree.map(lambda a: a[l], params["blocks"])
             h = apply_norm(p["norm1"], x, cfg)
-            a, kl, vl = attention_decode_paged(
-                p["attn"], h, cfg, k_pool=kp[l], v_pool=vp[l],
+            a, kp, vp = attention_decode_paged(
+                p["attn"], h, cfg, k_pool=kp, v_pool=vp, layer=l,
                 block_tables=block_tables, lengths=lengths,
                 contiguous=contiguous,
             )
@@ -524,8 +524,6 @@ class Model:
                 y, _ = moe_forward(p["moe"], h2, cfg)
             else:
                 y = apply_mlp(p["mlp"], h2, cfg)
-            kp = jax.lax.dynamic_update_index_in_dim(kp, kl, l, 0)
-            vp = jax.lax.dynamic_update_index_in_dim(vp, vl, l, 0)
             return (x + y, kp, vp), None
 
         carry = (x, k_pool, v_pool)
@@ -565,14 +563,13 @@ class Model:
         cfg = self.cfg
         x = embed_tokens(params["embed"], tokens, cfg)
 
-        # pools ride the scan carry with in-place dynamic updates, exactly
-        # like decode_step_paged -- a ys-stacked scan would copy the pool
+        # pools ride the scan carry whole, exactly like decode_step_paged
         def body(carry, l):
             x, kp, vp = carry
             p = jax.tree.map(lambda a: a[l], params["blocks"])
             h = apply_norm(p["norm1"], x, cfg)
-            a, kl, vl = attention_prefill_paged(
-                p["attn"], h, cfg, k_pool=kp[l], v_pool=vp[l],
+            a, kp, vp = attention_prefill_paged(
+                p["attn"], h, cfg, k_pool=kp, v_pool=vp, layer=l,
                 block_tables=block_tables, q_offsets=q_offsets,
                 n_valid=n_valid,
             )
@@ -582,8 +579,6 @@ class Model:
                 y, _ = moe_forward(p["moe"], h2, cfg)
             else:
                 y = apply_mlp(p["mlp"], h2, cfg)
-            kp = jax.lax.dynamic_update_index_in_dim(kp, kl, l, 0)
-            vp = jax.lax.dynamic_update_index_in_dim(vp, vl, l, 0)
             return (x + y, kp, vp), None
 
         carry = (x, k_pool, v_pool)

@@ -173,6 +173,72 @@ def test_ops_chunked_prefill_paged_dispatch():
                                atol=2e-5, rtol=2e-4)
 
 
+def _free_list_pool(b, h, hkv, d, page, p_max, lens, layers=3):
+    """A stacked pool [layers, N, page, Hkv, D] and a free-list table:
+    each row holds scattered pages for its length, and the scratch page 0
+    past them (a row of length 0 is all scratch)."""
+    n_pages = 1 + sum(-(-n // page) for n in lens) + 2
+    kp = _rand((layers, n_pages, page, hkv, d), jnp.float32)
+    vp = _rand((layers, n_pages, page, hkv, d), jnp.float32)
+    free = list(RNG.permutation(np.arange(1, n_pages)))
+    bt = np.zeros((b, p_max), np.int32)
+    for i, n in enumerate(lens):
+        for j in range(-(-n // page)):
+            bt[i, j] = free.pop()
+    return kp, vp, jnp.asarray(bt)
+
+
+# (b, sq, h, hkv, d, page, p_max, offs, lens); decode is sq 1 at len - 1.
+# D 64 on 128-token pages is the page-minor read, D 128 the token-major
+# one (``page_minor``).
+STACKED_CASES = {
+    "decode-rep2-d128": (4, 1, 4, 2, 128, 128, 3, None,
+                         [0, 200, 256, 384]),
+    "decode-rep8-d64": (4, 1, 16, 2, 64, 128, 3, None, [130, 0, 128, 1]),
+    "chunk-rep2-d64-mid-page": (2, 24, 4, 2, 64, 128, 2, [120, 0],
+                                [144, 0]),
+    "chunk-rep8-d128-to-boundary": (2, 16, 16, 2, 128, 128, 2, [240, 5],
+                                    [256, 21]),
+}
+
+
+@pytest.mark.parametrize("layer", [None, 1, 2],
+                         ids=["one-layer", "middle-layer", "last-layer"])
+@pytest.mark.parametrize("case", list(STACKED_CASES))
+def test_chunked_prefill_paged_reads_stacked_pool(case, layer):
+    """The stacked pool read in place at a layer == the oracle over that
+    layer's pool, through a free-list table with scattered and scratch
+    pages; decode goes through its own entry point."""
+    b, sq, h, hkv, d, page, p_max, offs, lens = STACKED_CASES[case]
+    kp, vp, bt = _free_list_pool(b, h, hkv, d, page, p_max, lens)
+    lens = jnp.asarray(lens, jnp.int32)
+    k_l, v_l = kp[layer or 1], vp[layer or 1]     # the layer read
+    k_in, v_in = (k_l, v_l) if layer is None else (kp, vp)
+    if sq == 1:
+        q = _rand((b, h, d), jnp.float32)
+        got = paged_attention(q, k_in, v_in, lens, block_tables=bt,
+                              layer=layer, interpret=True)
+        want = ref.paged_attention_ref(q, k_l, v_l, lens, block_tables=bt)
+    else:
+        q = _rand((b, sq, h, d), jnp.float32)
+        offs = jnp.asarray(offs, jnp.int32)
+        got = chunked_prefill_paged(q, k_in, v_in, lens, bt, offs,
+                                    layer=layer, interpret=True)
+        want = ref.chunked_prefill_paged_ref(q, k_l, v_l, lens, bt, offs)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-5, rtol=2e-4)
+    assert np.abs(np.asarray(got)[np.asarray(lens) == 0]).max(
+        initial=0.0) == 0.0
+
+
+def test_page_minor_follows_the_lane_dim():
+    from repro.kernels.chunked_prefill import page_minor
+
+    assert page_minor(128, 64) and page_minor(128, 96)
+    assert not page_minor(128, 128) and not page_minor(128, 256)
+    assert not page_minor(16, 64)
+
+
 # ---------------------------------------------------------------------------
 # paged attention (decode)
 # ---------------------------------------------------------------------------
@@ -372,6 +438,30 @@ def test_ops_paged_dispatch_block_tables():
                              impl="pallas")
     np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
                                atol=2e-5, rtol=2e-4)
+
+
+def test_ops_paged_dispatch_stacked_pool():
+    """ops routes a stacked pool and its layer to both implementations."""
+    h, hkv, d, page = 4, 2, 8, 4
+    lens = [5, 12]
+    kp, vp, bt = _free_list_pool(2, h, hkv, d, page, 3, lens)
+    q = _rand((2, h, d), jnp.float32)
+    lens = jnp.asarray(lens, jnp.int32)
+    qc = _rand((2, 3, h, d), jnp.float32)
+    offs = lens - 3
+    for layer in (0, 2):
+        a = ops.paged_attention(q, kp, vp, lens, block_tables=bt,
+                                layer=layer, impl="jnp")
+        b_ = ops.paged_attention(q, kp, vp, lens, block_tables=bt,
+                                 layer=layer, impl="pallas")
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                   atol=2e-5, rtol=2e-4)
+        a = ops.chunked_prefill_paged(qc, kp, vp, lens, bt, offs,
+                                      layer=layer, impl="jnp")
+        b_ = ops.chunked_prefill_paged(qc, kp, vp, lens, bt, offs,
+                                       layer=layer, impl="pallas")
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                   atol=2e-5, rtol=2e-4)
 
 
 def test_paged_attention_grouped_matches_repeat():

@@ -1,13 +1,17 @@
-"""The served path compiled for a described TPU v5e at TinyLlama widths.
+"""The served path compiled for a described TPU v5e.
 
 Nothing runs: the TPU compiler, installed with JAX, compiles for a chip
 that is described and not attached, and refuses what the chip would
 refuse -- a block shape off the (8, 128) tiling, a kernel over its fast
 memory, a program over the device's memory.  Shapes are TinyLlama-1.1B's
-published widths in bf16 (H 32, Hkv 4, head_dim 64, page 128); the whole
-decode and chunk-wave programs are compiled from ``jax.eval_shape``
-shapes, with a check that no program holds a second pool.
+published widths in bf16 (H 32, Hkv 4, head_dim 64, page 128), and for
+the step programs InternLM2-1.8B's too (H 16, Hkv 8, head_dim 128); the
+whole decode, mixed and chunk-wave programs are compiled from
+``jax.eval_shape`` shapes, with checks that no program holds a second
+pool or moves a layer of it.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -22,6 +26,7 @@ from repro.kernels.chunked_prefill import (
 from repro.kernels.paged_attention import paged_attention
 from repro.models import cache as cache_lib
 from repro.models.model import Model
+from repro.serving.executor import PagedExecutor
 
 CFG = get_config("skymemory-tinyllama")
 H, HKV, D = CFG.num_heads, CFG.num_kv_heads, CFG.head_dim
@@ -104,12 +109,13 @@ def _pool_bytes():
     return CFG.num_layers * N_PAGES * PAGE * HKV * D * 2
 
 
-def _check_in_place(compiled):
+def _check_in_place(compiled, pool_bytes=None):
     """Both pools donated and updated in place: aliased, and no second
     pool among the program's temporaries."""
+    pool_bytes = pool_bytes or _pool_bytes()
     mem = compiled.memory_analysis()
-    assert mem.alias_size_in_bytes >= 2 * _pool_bytes()
-    assert mem.temp_size_in_bytes < _pool_bytes()
+    assert mem.alias_size_in_bytes >= 2 * pool_bytes
+    assert mem.temp_size_in_bytes < pool_bytes
 
 
 @pytest.fixture(scope="module")
@@ -157,3 +163,97 @@ def test_page_restore_compiles_in_place(one_chip):
     compiled = cache_lib._put_pages.lower(
         *_pool_specs(one_chip), *args).compile()
     _check_in_place(compiled)
+
+
+# Pool sizes of the step programs' checks.  Both have a factor 7, which
+# no width of either model has, so a buffer of a whole number of pool
+# layers can only have come from the pool.
+POOL_PAGES = (224, 448)
+STEP_WIDTHS = ("internlm2-1.8b", "skymemory-tinyllama")
+_MOVES = re.compile(r"\s*(?:ROOT )?%(\S+) = (.+?) ([\w-]+)\(")
+_ARRAY = re.compile(r"[a-z]+\d*\[([\d,]*)\]")
+
+
+def _layer_moves(hlo: str, layer_elems: int) -> list[str]:
+    """Instructions, fused or not, named for a copy, transpose or
+    dynamic-slice whose result holds a whole number of pool layers, in
+    any layout."""
+    found = []
+    for line in hlo.splitlines():
+        m = _MOVES.match(line)
+        if not m or not re.search(r"copy|transpose|dynamic-slice",
+                                  m.group(1) + " " + m.group(3)):
+            continue
+        for dims in _ARRAY.findall(m.group(2)):
+            n = 1
+            for d in filter(None, dims.split(",")):
+                n *= int(d)
+            if n >= layer_elems and n % layer_elems == 0:
+                found.append(m.group(1))
+    return found
+
+
+@pytest.fixture(scope="module")
+def step_models(one_chip):
+    """Per width: the model, its parameters' shapes and the executor whose
+    step programs are compiled (built once, on first use)."""
+    built = {}
+
+    def get(name):
+        if name not in built:
+            cfg = get_config(name).replace(dtype="bfloat16")
+            model = Model(cfg)
+            params = jax.tree.map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                               sharding=one_chip),
+                jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+            ex = PagedExecutor(model, params, _FreeListPool(),
+                               chunk_tokens=CHUNK, max_seq_len=MAX_SEQ)
+            built[name] = cfg, params, ex
+        return built[name]
+
+    return get
+
+
+class _FreeListPool:
+    contiguous = False
+
+
+def _lower_step(one_chip, cfg, params, ex, program, pages):
+    pool = _specs(one_chip, ((cfg.num_layers, pages, PAGE, cfg.num_kv_heads,
+                              cfg.head_dim), BF16))[0]
+    i32 = lambda *s: _specs(one_chip, (s, jnp.int32))[0]      # noqa: E731
+    f32 = lambda *s: _specs(one_chip, (s, jnp.float32))[0]    # noqa: E731
+    dec = (params, pool, pool, i32(BATCH, P), i32(BATCH), i32(BATCH),
+           _specs(one_chip, ((2,), jnp.uint32))[0], f32(BATCH), i32(BATCH),
+           f32(BATCH))
+    if program == "decode":
+        return ex._step.lower(*dec, mode="greedy")
+    if program == "mixed":
+        return ex._mixed.lower(*dec, i32(1, CHUNK), i32(1, P), i32(1),
+                               i32(1), f32(1), i32(1), f32(1), mode="greedy")
+    return ex._chunk_wave.lower(params, pool, pool, i32(BATCH, CHUNK),
+                                i32(BATCH, P), i32(BATCH), i32(BATCH))
+
+
+@pytest.mark.parametrize("width", STEP_WIDTHS)
+@pytest.mark.parametrize("program", ["decode", "mixed", "chunk_wave"])
+def test_step_reads_pool_in_place(one_chip, step_models, compiled_kernels,
+                                  program, width):
+    """The decode step, the mixed step at the largest chunk buffer and the
+    chunk wave read and write the carried pools in place: no instruction
+    slices, copies or transposes a layer of a pool (or the whole pool),
+    and the temporaries do not grow with the pool."""
+    cfg, params, ex = step_models(width)
+    page_elems = PAGE * cfg.num_kv_heads * cfg.head_dim
+    temps = []
+    for pages in POOL_PAGES:
+        compiled = _lower_step(one_chip, cfg, params, ex, program,
+                               pages).compile()
+        assert "tpu_custom_call" in compiled.as_text()
+        assert _layer_moves(compiled.as_text(), pages * page_elems) == []
+        pool_bytes = cfg.num_layers * pages * page_elems * 2
+        _check_in_place(compiled, pool_bytes)
+        temps.append(compiled.memory_analysis().temp_size_in_bytes)
+    page_bytes = 2 * cfg.num_layers * page_elems * 2     # both pools
+    assert abs(temps[1] - temps[0]) < page_bytes
